@@ -497,7 +497,7 @@ def bench_oom(rows, *, fast: bool = False,
               json_path: str | None = "BENCH_5.json",
               store_dir: str | None = None) -> dict:
     """Memory-hierarchy MTTKRP (ISSUE 5): in-memory vs host-streamed vs
-    DISK-streamed, all bit-identical, through one engine API.
+    DISK-streamed, equal to f32 rounding, through one engine API.
 
     Builds a many-launch BLCO, spills it to the persistent store
     (measuring write cost + file size), and times a full MTTKRP per tier:
@@ -549,10 +549,15 @@ def bench_oom(rows, *, fast: bool = False,
         t_disk = _time(lambda: disk.mttkrp(factors, mode),
                        warmup=warmup, iters=iters)
 
-        # bit-identical across all three tiers (cheap insurance here)
+        # equal across all three tiers to f32 rounding (cheap insurance):
+        # the tiers run separately compiled programs whose sums XLA may
+        # associate differently, so within 1e-6 of the largest output
         m0 = np.asarray(mem.mttkrp(factors, mode))
-        if not (np.array_equal(m0, np.asarray(host.mttkrp(factors, mode)))
-                and np.array_equal(m0, np.asarray(disk.mttkrp(factors, mode)))):
+        atol = 1e-6 * float(np.max(np.abs(m0)))
+        if not (np.allclose(m0, np.asarray(host.mttkrp(factors, mode)),
+                            rtol=0, atol=atol)
+                and np.allclose(m0, np.asarray(disk.mttkrp(factors, mode)),
+                                rtol=0, atol=atol)):
             raise AssertionError("memory-tier MTTKRP results diverged")
 
         nnz_bytes = core.format_bytes(b)
@@ -589,7 +594,7 @@ def bench_oom(rows, *, fast: bool = False,
         "backend": _jax_backend(),
         "note": ("One MTTKRP per memory tier (device-resident launch "
                  "cache / host-streamed lazy chunks / disk-streamed mmap "
-                 "store), bit-identical outputs.  host_window_bytes is "
+                 "store), outputs equal to f32 rounding.  host_window_bytes is "
                  "the bounded padded-chunk window the streaming loop "
                  "holds (queues x reservation); ratio_vs_all_launches "
                  "is what the lazy-padding fix saves over the old eager "
@@ -1016,6 +1021,8 @@ def main(argv=None) -> None:
                     help="where to write the bandwidth-ledger / roofline "
                          "bench (default: BENCH_7.json; '' disables)")
     args = ap.parse_args(argv)
+    from repro.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rows: list[tuple[str, float, str]] = []
     print("# BLCO paper benchmarks (CPU-scale analogues; see EXPERIMENTS.md)")

@@ -1,32 +1,30 @@
 """Distributed CP-ALS over a device mesh (beyond-paper scale-out).
 
 nnz shard over the `data` axis (one psum per mode), rank shards over the
-`model` axis (zero-communication in MTTKRP). Runs on 8 fake XLA CPU devices
-here; the identical code targets the 16x16 pod mesh.
+`model` axis (zero-communication in MTTKRP). The mesh spans every device
+JAX sees: the chips of a TPU host, or fake CPU devices.
 
 With a mesh installed in ``repro.dist.context``, the engine's regime
 decision routes MTTKRP execution through the sharded backend automatically:
 ``plan_for`` returns a ``ShardedPlan`` and CP-ALS runs on it unchanged.
 
+    PYTHONPATH=src python examples/distributed_cpals.py     # TPU host
     PYTHONPATH=src XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        python examples/distributed_cpals.py
+        python examples/distributed_cpals.py                 # CPU
 """
-import os
+import numpy as np
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                               " --xla_force_host_platform_device_count=8")
+from repro import core
+from repro.dist.context import set_mesh
+from repro.engine import plan_for
+from repro.launch.mesh import make_test_mesh
 
-import numpy as np                                    # noqa: E402
-import jax                                            # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P   # noqa: E402
-
-from repro import core                                # noqa: E402
-from repro.dist.context import set_mesh               # noqa: E402
-from repro.engine import plan_for                     # noqa: E402
-from repro.launch.mesh import make_test_mesh          # noqa: E402
-
-mesh = make_test_mesh((4, 2), ("data", "model"))
+# rank splits in two where the device count is even; nnz over the rest
+n_dev = len(jax.devices())
+model = 2 if n_dev % 2 == 0 else 1
+mesh = make_test_mesh((n_dev // model, model), ("data", "model"))
 set_mesh(mesh)                 # active mesh -> plan_for routes to sharded
 print(f"mesh: {dict(mesh.shape)} over {len(jax.devices())} devices")
 

@@ -12,6 +12,7 @@ plan, exactly as it would on an in-memory one.
 import numpy as np
 
 from repro import core
+from repro.core.launches import launch_cache_bytes
 from repro.engine import factor_bytes, in_memory_bytes, plan_for
 
 # "amazon-like" scale-down: 170k nnz, 3 long modes (paper Table 2 analogue)
@@ -21,12 +22,14 @@ print(f"tensor dims={t.dims} nnz={t.nnz:,}")
 # deliberately tiny per-launch reservation -> many streamed launches,
 # emulating a tensor far larger than device memory
 b = core.build_blco(t, max_nnz_per_block=1 << 13)
-# budget covers the factor working set but only HALF the tensor -> stream
-budget = factor_bytes(b.dims, 16, np.float32) + in_memory_bytes(b) // 2
+# budget covers the factor working set and the scan's temporaries but only
+# HALF the resident tensor -> stream
+budget = factor_bytes(b.dims, 16, np.float32) + in_memory_bytes(b, 16) \
+    - launch_cache_bytes(b) // 2
 plan = plan_for(b, budget, rank=16, queues=4)
 assert plan.backend == "streamed", plan.backend
 print(f"budget {budget/1e6:.1f} MB cannot hold the "
-      f"{in_memory_bytes(b)/1e6:.1f} MB tensor + factors "
+      f"{in_memory_bytes(b, 16)/1e6:.1f} MB tensor + factors "
       f"-> backend={plan.backend!r}: {len(b.launches)} launches of "
       f"<= {plan.spec.nnz:,} nnz, {plan.device_bytes()/1e6:.1f} MB in flight")
 

@@ -21,8 +21,8 @@ measurably frees pooled bytes.
 A third act exercises the **persistent store**: the service is killed
 mid-decomposition, restarted from its snapshot + spill store, and the job
 resumes from its checkpointed ``CPState`` — disk-streaming the tensor
-straight off the store, with no BLCO rebuild and a numerically identical
-trajectory.
+straight off the store, with no BLCO rebuild and the same fit trajectory
+to f32 rounding.
 
     PYTHONPATH=src python examples/serve_td.py
 """
@@ -33,7 +33,8 @@ import tempfile
 import numpy as np
 
 from repro import core
-from repro.engine import factor_bytes, in_memory_bytes, plan_for
+from repro.core.launches import launch_cache_bytes
+from repro.engine import factor_bytes, plan_for
 from repro.service import (BuildParams, CancelJob, DecompositionService,
                            ServiceRuntime, SubmitDecomposition)
 
@@ -53,13 +54,13 @@ chicago_stream = reservation_for(b_chicago).bytes_in_flight(4)
 fb_uber = factor_bytes(t_uber.dims, 8, np.float32)
 fb_ch16 = factor_bytes(t_chicago.dims, 16, np.float32)
 fb_ch8 = factor_bytes(t_chicago.dims, 8, np.float32)
-budget = in_memory_bytes(b_uber) + 2 * fb_uber \
+budget = launch_cache_bytes(b_uber) + 2 * fb_uber \
     + chicago_stream + fb_ch16 + fb_ch8 + (32 << 10)
 # chicago can never go resident: when its first job is admitted (tenantA's
 # uber copy + working set already held), the remaining budget is below
 # chicago's residency cost + its working set
-assert budget - in_memory_bytes(b_uber) - fb_uber \
-    < in_memory_bytes(b_chicago) + fb_ch16
+assert budget - launch_cache_bytes(b_uber) - fb_uber \
+    < launch_cache_bytes(b_chicago) + fb_ch16
 
 svc = DecompositionService(device_budget_bytes=budget, queues=4)
 jobs = {
@@ -108,7 +109,7 @@ assert m["blco_cache_misses"] == 2     # one build per distinct tensor
 
 # the multi-tenant result is exactly the solo result through the same regime
 jid = jobs["tenantA/uber"]
-solo_plan = plan_for(b_uber, budget, rank=8, backend="in_memory")
+solo_plan = plan_for(b_uber, 1 << 40, rank=8, backend="in_memory")
 solo = core.cp_als(solo_plan, t_uber.dims, 8,
                    norm_x=float(np.linalg.norm(t_uber.values)),
                    iters=6, seed=1)
@@ -123,10 +124,10 @@ print("multi-tenant factors == solo engine factors (same seeds): OK")
 # ---------------------------------------------------------------------------
 print("\n== async runtime (weighted shares + streaming + cancellation) ==")
 # three uber tenants (3 working sets) + one streaming chicago tenant
-budget2 = in_memory_bytes(b_uber) + 3 * fb_uber \
+budget2 = launch_cache_bytes(b_uber) + 3 * fb_uber \
     + chicago_stream + fb_ch16 + (32 << 10)
 assert chicago_stream + fb_ch16 + (32 << 10) \
-    < in_memory_bytes(b_chicago) + fb_ch16
+    < launch_cache_bytes(b_chicago) + fb_ch16
 with ServiceRuntime(device_budget_bytes=budget2, queues=4) as rt:
     feed = rt.subscribe()                       # all-jobs status stream
     ja = rt.submit(SubmitDecomposition(tensor=t_uber, rank=8, iters=8,
@@ -172,7 +173,7 @@ store_dir = os.path.join(workdir, "store")
 snap_dir = os.path.join(workdir, "snapshot")
 ITERS = 10
 
-# the uninterrupted trajectory we must exactly reproduce across the restart
+# the uninterrupted trajectory we must reproduce across the restart
 ref = DecompositionService(device_budget_bytes=budget, store_dir=store_dir)
 ref_job = ref.submit(SubmitDecomposition(tensor=t_uber, rank=8, iters=ITERS,
                                          tol=0.0, seed=7, build=build,
@@ -212,9 +213,12 @@ print(f"  restored under job id {job}: resumed at iter {ckpt_iter}, "
       f"finished at iter {final.iteration} backend={final.backend}")
 assert final.state == "done" and final.iteration == ITERS
 assert final.backend == "disk_streamed"         # streams straight off the store
-assert fits == ref_fits                         # trajectory exactly preserved
+# trajectory preserved to f32 rounding: the resumed job disk-streams where
+# the uninterrupted one ran device-resident, and the two compiled MTTKRPs
+# may associate their sums differently
+np.testing.assert_allclose(fits, ref_fits, rtol=0, atol=1e-5)
 assert m3["jobs_restored"] == 1 and m3["blco_cache_misses"] == 0
 print(f"  resumed fit trajectory == uninterrupted run ({len(fits)} sweeps, "
-      f"exact); disk-streamed {m3['h2d_bytes_total']/1e6:.1f}MB from the store")
+      f"to 1e-5); disk-streamed {m3['h2d_bytes_total']/1e6:.1f}MB from the store")
 shutil.rmtree(workdir)
-print("persistent store: kill -> restart -> exact resume: OK")
+print("persistent store: kill -> restart -> resume: OK")

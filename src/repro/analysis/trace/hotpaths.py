@@ -67,19 +67,18 @@ def _stacked(resolution: str):
         _u32(launches, _RES), _u32(launches, _RES), _f32(launches, _RES),
         _i32(launches, _RES, len(_DIMS)), _factors(),
         re_fields=_FIELDS, re_shifts=_SHIFTS, mode=0, out_rows=_DIMS[0],
-        resolution=resolution, copies=8)
+        resolution=resolution, copies=8, step=_RES // 2)
 
 
 def _launch_body(resolution: str):
-    # the per-launch dataflow shared by the scan body AND the streamed
-    # regime (stream_mttkrp dispatches exactly this, one launch at a time)
-    from repro.core.mttkrp import launch_mttkrp_impl
+    # the streamed regime dispatches the same scan over one launch at a time
+    from repro.core.launches import stacked_mttkrp
     return trace_jaxpr(
-        launch_mttkrp_impl,
+        stacked_mttkrp,
         _u32(_RES), _u32(_RES), _f32(_RES), _i32(_RES, len(_DIMS)),
         _factors(),
         re_fields=_FIELDS, re_shifts=_SHIFTS, mode=0, out_rows=_DIMS[0],
-        resolution=resolution, copies=8)
+        resolution=resolution, copies=8, step=_RES)
 
 
 def _fused(variant: str):
@@ -106,7 +105,7 @@ def registered_hot_paths() -> list[HotPath]:
                 lambda: _stacked("register")),
         HotPath("stacked_mttkrp[hierarchical]", "src/repro/core/launches.py",
                 lambda: _stacked("hierarchical")),
-        HotPath("launch_mttkrp_impl[streamed]", "src/repro/core/streaming.py",
+        HotPath("stacked_mttkrp[streamed]", "src/repro/core/streaming.py",
                 lambda: _launch_body("register")),
         HotPath("_fused_flat[segment]", "src/repro/kernels/fused.py",
                 lambda: _fused("segment")),

@@ -1,6 +1,7 @@
 """Core library: the paper's contribution (BLCO format + mode-agnostic MTTKRP
 + OOM streaming + CP-ALS) and its baselines."""
-from .tensor import SparseTensor, random_tensor, from_coo, load_tns, paper_like
+from .tensor import (SparseTensor, random_tensor, random_tensor_exact, from_coo,
+                     load_tns, paper_like, frostt_like)
 from .blco import BLCOTensor, build_blco, decode_coords, format_bytes
 from .mttkrp import (mttkrp, mttkrp_per_launch, choose_resolution,
                      clear_launch_cache, launch_cache_for,
@@ -16,7 +17,8 @@ from .streaming import (EngineStats, LaunchChunks, OOMExecutor,
 from .embed_grad import embedding_lookup
 
 __all__ = [
-    "SparseTensor", "random_tensor", "from_coo", "load_tns", "paper_like",
+    "SparseTensor", "random_tensor", "random_tensor_exact", "from_coo",
+    "load_tns", "paper_like", "frostt_like",
     "BLCOTensor", "build_blco", "decode_coords", "format_bytes",
     "mttkrp", "mttkrp_per_launch", "choose_resolution",
     "clear_launch_cache", "launch_cache_for",

@@ -18,6 +18,7 @@ import time
 import numpy as np
 
 from . import linearize as lin
+from .padding import default_launch_nnz, device_memory_bytes
 from .tensor import SparseTensor
 from .u64 import split64
 
@@ -89,17 +90,24 @@ class BLCOTensor:
 
 
 def build_blco(t: SparseTensor, *, target_bits: int = 64,
-               max_nnz_per_block: int = 1 << 27,
+               max_nnz_per_block: int | None = None,
                launch_nnz_budget: int | None = None) -> BLCOTensor:
     """Construct the BLCO representation of a COO tensor.
 
     target_bits: native integer width of the device (64 in the paper; smaller
         values exercise the blocking machinery on small test tensors).
-    max_nnz_per_block: device memory constraint (2^27 in the paper).
+    max_nnz_per_block: device memory constraint (2^27 in the paper).  By
+        default derived from the device (``padding.default_launch_nnz``):
+        one launch's slots take at most 1/256 of its memory, 2^21 nnz at
+        order 3 on a 16 GB chip, so the streamed regime's queues fit far
+        below the tensor; the paper's 2^27 where the device reports no limit.
     launch_nnz_budget: batch blocks into launches of at most this many nnz
         (defaults to max_nnz_per_block) — the paper's work-group batching for
         hypersparse tensors.
     """
+    if max_nnz_per_block is None:
+        max_nnz_per_block = default_launch_nnz(
+            t.order, t.values.dtype.itemsize, device_memory_bytes())
     stats: dict[str, float] = {}
     t0 = time.perf_counter()
     spec = lin.LinearSpec.make(t.dims)

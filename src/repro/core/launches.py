@@ -12,7 +12,8 @@ per launch per ``mttkrp`` call.  This module is the fix:
   + ...`` chain with a single jitted ``lax.scan`` over the stacked
   launches — ONE dispatch per MTTKRP call regardless of launch count, and
   per-step intermediates (coordinates, gathered factor rows) bounded by the
-  reservation size instead of the full nnz count.
+  scan step (:func:`scan_step_for`: the reservation, cut further where its
+  temporaries would crowd device memory) instead of the full nnz count.
 
 The stacked arrays are also the zero-copy source for the fused Pallas
 pipeline (``repro.kernels.fused``): ``flat()`` reshapes ``(L, reservation)``
@@ -32,7 +33,9 @@ from repro.obs import trace as obs_trace
 from .blco import BLCOTensor
 from .counters import record_dispatch
 from .mttkrp import (DEFAULT_COPIES, choose_resolution, launch_mttkrp_impl)
-from .padding import pad_bucket, pad_multiple
+from . import padding
+from .padding import (device_memory_bytes, nnz_bytes, pad_bucket, pad_multiple,
+                      scan_step)
 
 
 def default_reservation(max_launch: int) -> int:
@@ -48,21 +51,45 @@ def default_reservation(max_launch: int) -> int:
     return pad_bucket(max_launch)
 
 
+def scan_step_for(reservation: int, order: int, rank: int,
+                  itemsize: int) -> int:
+    """The scan step of a ``reservation``-slot launch at rank ``rank`` on the
+    default device."""
+    return scan_step(reservation, order=order, rank=rank, itemsize=itemsize,
+                     device_bytes=device_memory_bytes())
+
+
+def scan_temp_bytes(reservation: int, order: int, rank: int,
+                    itemsize: int) -> int:
+    """Predicted device temporaries of the scan on the default device (the
+    working set it adds on top of the resident tensor and factors)."""
+    return padding.scan_temp_bytes(reservation, order=order, rank=rank,
+                                   itemsize=itemsize,
+                                   device_bytes=device_memory_bytes())
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("re_fields", "re_shifts", "mode", "out_rows",
-                     "resolution", "copies"))
+                     "resolution", "copies", "step"))
 def stacked_mttkrp(hi, lo, vals, bases, factors, *,
                    re_fields: tuple, re_shifts: tuple, mode: int,
-                   out_rows: int, resolution: str, copies: int):
-    """Single-dispatch MTTKRP over stacked launches.
+                   out_rows: int, resolution: str, copies: int, step: int):
+    """Single-dispatch MTTKRP over a stream of launches.
 
-    hi/lo: (L, R) uint32; vals: (L, R); bases: (L, R, N) int32; factors:
-    tuple of (I_n, R) arrays.  ``lax.scan`` runs the per-launch dataflow
-    sequentially on device, accumulating into one (out_rows, rank) output —
-    the launch order (and therefore the floating-point accumulation order)
-    matches the legacy per-launch loop exactly.
+    hi/lo: (..., S) uint32; vals: (..., S); bases: (..., S, N) int32 — one
+    launch (the streamed regime) or a stack of L launches (the launch
+    cache); factors: tuple of (I_n, R) arrays.  The stream is cut into
+    consecutive steps of ``step`` slots (``step`` divides S) and
+    ``lax.scan`` runs the per-launch dataflow on each step in order,
+    accumulating into one (out_rows, rank) output.  Steps never need launch
+    boundaries: every slot carries its block's coordinate bases.  With
+    ``step == S`` the accumulation order is the per-launch loop's.
     """
+    hi = hi.reshape(-1, step)
+    lo = lo.reshape(-1, step)
+    vals = vals.reshape(-1, step)
+    bases = bases.reshape(-1, step, bases.shape[-1])
     factors = tuple(factors)
     rank = factors[0].shape[1]
     # accumulate at the promoted precision: float64 tensor values against
@@ -200,6 +227,9 @@ class LaunchCache:
             rank = factors[0].shape[1]
             return jnp.zeros((self.dims[mode], rank),
                              jnp.result_type(self.vals, factors[0]))
+        step = scan_step_for(
+            self.reservation, self.order, factors[0].shape[1],
+            jnp.result_type(self.vals, factors[0]).itemsize)
         record_dispatch()
         # span covers the host-side issue of the one scan dispatch (async);
         # the fenced device time is the plan's device.fence event
@@ -208,7 +238,8 @@ class LaunchCache:
             return stacked_mttkrp(
                 self.hi, self.lo, self.vals, self.bases, factors,
                 re_fields=self.re_fields, re_shifts=self.re_shifts, mode=mode,
-                out_rows=self.dims[mode], resolution=resolution, copies=copies)
+                out_rows=self.dims[mode], resolution=resolution, copies=copies,
+                step=step)
 
     # ---------------------------------------------------------------- release
     def delete(self) -> None:
@@ -229,5 +260,5 @@ def launch_cache_bytes(blco: BLCOTensor) -> int:
         return 0
     max_launch = max(l.nnz for l in blco.launches)
     res = default_reservation(max_launch)
-    per_elem = 4 + 4 + blco.values.dtype.itemsize + 4 * blco.order
-    return len(blco.launches) * res * per_elem
+    return len(blco.launches) * res * nnz_bytes(blco.order,
+                                                blco.values.dtype.itemsize)

@@ -45,10 +45,14 @@ KERNELS = ("xla", "pallas")
 
 
 def validate_kernel(kernel: str) -> str:
-    """Reject unknown compute-kernel names (one validator for every layer)."""
+    """Reject unknown compute-kernel names, and the fused Pallas kernel
+    where it cannot compile (one validator for every layer)."""
     if kernel not in KERNELS:
         raise ValueError(f"unknown kernel {kernel!r}; expected one of "
                          f"{KERNELS}")
+    if kernel == "pallas":
+        from repro.kernels.fused import require_fused_support
+        require_fused_support()
     return kernel
 
 
@@ -244,7 +248,7 @@ class DeviceBLCO:
     """
 
     def __init__(self, blco: BLCOTensor, *, kernel: str = "xla",
-                 reservation_nnz: int | None = None, interpret: bool = True):
+                 reservation_nnz: int | None = None):
         from .launches import LaunchCache
         validate_kernel(kernel)
         self.cache = LaunchCache.from_blco(blco,
@@ -252,7 +256,6 @@ class DeviceBLCO:
         self.dims = blco.dims
         self.order = blco.order
         self.kernel = kernel
-        self.interpret = interpret
 
     def device_bytes(self) -> int:
         """Exact device footprint: hi + lo + vals + bases (stacked, padded)."""
@@ -264,8 +267,7 @@ class DeviceBLCO:
         if kernel == "pallas":
             from repro.kernels.fused import fused_cache_mttkrp
             return fused_cache_mttkrp(self.cache, factors, mode,
-                                      resolution=resolution,
-                                      interpret=self.interpret)
+                                      resolution=resolution)
         return self.cache.mttkrp(factors, mode, resolution=resolution,
                                  copies=copies)
 

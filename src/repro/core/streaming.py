@@ -46,7 +46,8 @@ from repro.obs.hist import EngineHists
 
 from .blco import BLCOTensor
 from .counters import record_dispatch
-from .mttkrp import launch_mttkrp, choose_resolution, DEFAULT_COPIES
+from .launches import scan_step_for, stacked_mttkrp
+from .mttkrp import choose_resolution, DEFAULT_COPIES
 from .padding import next_pow2 as _next_pow2
 
 
@@ -128,7 +129,15 @@ class ReservationSpec:
         return self.nnz * (4 + 4 + self.value_itemsize + 4 * self.order)
 
     def bytes_in_flight(self, queues: int) -> int:
+        """Device bytes of ``queues`` launch buffers in flight (the pooled
+        reservation; the scan's per-call temporaries are counted apart,
+        by :meth:`temp_bytes`)."""
         return self.bytes_per_launch * queues
+
+    def temp_bytes(self, rank: int, itemsize: int) -> int:
+        """Device temporaries of the scan over one launch at rank ``rank``."""
+        from .launches import scan_temp_bytes
+        return scan_temp_bytes(self.nnz, self.order, rank, itemsize)
 
 
 def reservation_for(blco: BLCOTensor,
@@ -207,7 +216,7 @@ def stream_mttkrp(chunks, blco: BLCOTensor, factors, mode: int, *,
                   queues: int, resolution: str = "auto",
                   copies: int = DEFAULT_COPIES,
                   stats: StreamStats | None = None,
-                  kernel: str = "xla", interpret: bool = True):
+                  kernel: str = "xla"):
     """Stream reservation chunks through the launch kernel.
 
     Keeps up to ``queues`` H2D transfers in flight ahead of compute (the
@@ -240,6 +249,7 @@ def stream_mttkrp(chunks, blco: BLCOTensor, factors, mode: int, *,
                                 factors[0])
     out = jnp.zeros((b.dims[mode], rank), out_dtype)
     stats = stats if stats is not None else StreamStats()
+    step = None                       # the reservation's scan step, once known
 
     t_start = time.perf_counter()
     in_flight: list[tuple] = []
@@ -276,7 +286,7 @@ def stream_mttkrp(chunks, blco: BLCOTensor, factors, mode: int, *,
         return dev, n
 
     def _consume(item):
-        nonlocal out, t_first_dispatch, nnz_total
+        nonlocal out, t_first_dispatch, nnz_total, step
         (hi, lo, vals, bases), n = item
         t0 = time.perf_counter()
         if t_first_dispatch is None:
@@ -286,15 +296,17 @@ def stream_mttkrp(chunks, blco: BLCOTensor, factors, mode: int, *,
             out = out + fused_mttkrp_flat(
                 hi, lo, vals, bases, factors,
                 field_bits=b.re.field_bits, field_shifts=b.re.field_shift,
-                mode=mode, out_rows=b.dims[mode], resolution=resolution,
-                interpret=interpret)
+                mode=mode, out_rows=b.dims[mode], resolution=resolution)
         else:
+            if step is None:
+                step = scan_step_for(int(hi.shape[0]), b.order, rank,
+                                     np.dtype(out_dtype).itemsize)
             record_dispatch()
-            out = out + launch_mttkrp(
+            out = out + stacked_mttkrp(
                 hi, lo, vals, bases, factors,
                 re_fields=b.re.field_bits, re_shifts=b.re.field_shift,
                 mode=mode, out_rows=b.dims[mode],
-                resolution=resolution, copies=copies)
+                resolution=resolution, copies=copies, step=step)
         # host wall time of the (async) dispatch only — NOT device compute
         t1 = time.perf_counter()
         stats.dispatch_time_s += t1 - t0

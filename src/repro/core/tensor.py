@@ -86,15 +86,9 @@ def random_tensor(dims, nnz, *, seed=0, dtype=np.float32, dist="uniform") -> Spa
     n = len(dims)
     nnz = int(nnz)
     idx = np.empty((nnz, n), dtype=np.int64)
-    if dist == "uniform":
+    if dist in ("uniform", "powerlaw"):
         for m, d in enumerate(dims):
-            idx[:, m] = rng.integers(0, d, size=nnz)
-    elif dist == "powerlaw":
-        for m, d in enumerate(dims):
-            # Zipf over the mode, clipped to the mode length.
-            z = rng.zipf(1.3, size=nnz) - 1
-            idx[:, m] = np.minimum(z, d - 1)
-            rng.shuffle(idx[:, m])  # decorrelate rank across modes
+            idx[:, m] = _draw_mode(rng, d, nnz, dist)
     elif dist == "clustered":
         k = max(1, min(8, min(dims) // 2))
         centers = np.stack([rng.integers(0, d, size=k) for d in dims], axis=1)
@@ -109,6 +103,51 @@ def random_tensor(dims, nnz, *, seed=0, dtype=np.float32, dist="uniform") -> Spa
     # avoid exact zeros (degenerate nnz)
     vals = np.where(vals == 0, np.asarray(1.0, dtype), vals).astype(dtype)
     return _dedupe(idx, vals, dims)
+
+
+def _draw_mode(rng, d: int, nnz: int, dist: str) -> np.ndarray:
+    """``nnz`` coordinates of one mode of length ``d``."""
+    if dist == "uniform":
+        return rng.integers(0, d, size=nnz)
+    # Zipf over the mode, clipped to the mode length
+    z = np.minimum(rng.zipf(1.3, size=nnz) - 1, d - 1)
+    rng.shuffle(z)  # decorrelate rank across modes
+    return z
+
+
+def _draw_keys(rng, dims, nnz: int, dist: str) -> np.ndarray:
+    """Row-major linear keys of ``nnz`` coordinate draws."""
+    keys = np.zeros(nnz, np.int64)
+    for d in dims:
+        keys *= d
+        keys += _draw_mode(rng, d, nnz, dist)
+    return keys
+
+
+def random_tensor_exact(dims, nnz, *, seed=0, dtype=np.float32,
+                        dist="powerlaw") -> SparseTensor:
+    """Synthetic tensor with exactly ``nnz`` distinct coordinates.
+
+    ``nnz`` draws from ``dist`` (as in :func:`random_tensor`), deduplicated,
+    then topped up with uniform draws until ``nnz`` distinct coordinates
+    remain: a skewed head over a uniform tail.  Power-law draws repeat
+    heavily (at NELL-2 dims about four in five of 77M draws are repeats),
+    so without the top-up a published nnz cannot be reached.  Works on
+    int64 linear keys, O(nnz) host memory, and needs ``prod(dims) < 2^63``.
+    """
+    rng = np.random.default_rng(seed)
+    dims = tuple(int(d) for d in dims)
+    nnz = int(nnz)
+    if float(np.prod([float(d) for d in dims])) >= 2.0 ** 63:
+        raise ValueError(f"dims {dims} exceed int64 linear keys")
+    keys = np.unique(_draw_keys(rng, dims, nnz, dist))
+    while len(keys) < nnz:
+        keys = np.union1d(keys, _draw_keys(rng, dims, nnz - len(keys),
+                                           "uniform"))
+    indices = np.stack(np.unravel_index(keys, dims), axis=1).astype(np.int64)
+    vals = rng.standard_normal(nnz).astype(dtype)
+    vals[vals == 0] = 1
+    return SparseTensor(dims, indices, vals)
 
 
 def from_coo(indices, values, dims) -> SparseTensor:
@@ -142,3 +181,19 @@ PAPER_LIKE_SUITE = {
 def paper_like(name: str, *, seed=0, dtype=np.float32) -> SparseTensor:
     dims, nnz, dist = PAPER_LIKE_SUITE[name]
     return random_tensor(dims, nnz, seed=seed, dtype=dtype, dist=dist)
+
+
+# Published dims and nnz of FROSTT tensors (paper Table 2), unscaled.
+FROSTT_SUITE = {
+    # name: (dims, nnz, dist)
+    "nell-2": ((12092, 9184, 28818), 76_879_419, "powerlaw"),
+}
+
+
+def frostt_like(name: str, *, seed=0, nnz: int | None = None,
+                dtype=np.float32) -> SparseTensor:
+    """A FROSTT tensor's shape at its published dims and exactly its nnz
+    (or ``nnz``, a cut), generated from ``seed``."""
+    dims, full, dist = FROSTT_SUITE[name]
+    return random_tensor_exact(dims, full if nnz is None else nnz, seed=seed,
+                               dtype=dtype, dist=dist)

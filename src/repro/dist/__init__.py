@@ -1,4 +1,4 @@
-"""Distributed substrate: mesh context, parameter sharding rules, compat.
+"""Distributed substrate: mesh context and parameter sharding rules.
 
 Split out of the model/launch layers so every consumer (models, launch,
 trainer, benchmarks, tests) shares one source of truth:
@@ -6,10 +6,8 @@ trainer, benchmarks, tests) shares one source of truth:
 * ``context``  — process-wide mesh registry + logical-axis activation
   constraints ("dp" = the data/ZeRO axes, "tp" = the model axis);
 * ``sharding`` — shape-only parameter partition specs (ZeRO/TP planning
-  that works on ``jax.eval_shape`` trees, no devices needed);
-* ``compat``   — thin wrappers over jax APIs that moved between releases
-  (``shard_map``, mesh construction).
+  that works on ``jax.eval_shape`` trees, no devices needed).
 """
-from . import compat, context, sharding
+from . import context, sharding
 
-__all__ = ["compat", "context", "sharding"]
+__all__ = ["context", "sharding"]

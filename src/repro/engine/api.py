@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import Protocol, runtime_checkable
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.blco import BLCOTensor
@@ -70,9 +71,21 @@ def factor_bytes(dims, rank: int, dtype) -> int:
         * rank * item
 
 
-def in_memory_bytes(blco: BLCOTensor) -> int:
-    """Predicted device footprint of an ``InMemoryPlan`` for ``blco``:
-    the stacked launch cache's hi + lo + vals + bases — L launches padded
-    to the lane-multiple reservation, exactly what ``DeviceBLCO`` holds."""
-    from repro.core.launches import launch_cache_bytes
-    return launch_cache_bytes(blco)
+def accumulator_itemsize(value_dtype, dtype) -> int:
+    """Bytes per element of the MTTKRP accumulator (values promoted
+    against the factor dtype, never downcast)."""
+    return jnp.result_type(np.dtype(value_dtype), np.dtype(dtype)).itemsize
+
+
+def in_memory_bytes(blco: BLCOTensor, rank: int, dtype=jnp.float32) -> int:
+    """Predicted device need of an ``InMemoryPlan`` for ``blco`` at rank R:
+    the stacked launch cache's hi + lo + vals + bases (L launches padded to
+    the size-class reservation, exactly what ``DeviceBLCO`` holds) plus
+    one scan step's temporaries at that rank."""
+    from repro.core.launches import (default_reservation, launch_cache_bytes,
+                                     scan_temp_bytes)
+    if not blco.launches:
+        return 0
+    res = default_reservation(max(l.nnz for l in blco.launches))
+    return launch_cache_bytes(blco) + scan_temp_bytes(
+        res, blco.order, rank, accumulator_itemsize(blco.values.dtype, dtype))

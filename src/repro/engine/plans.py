@@ -49,8 +49,7 @@ class InMemoryPlan:
 
     def __init__(self, blco: BLCOTensor, *, resolution: str = "auto",
                  copies: int = DEFAULT_COPIES, device: DeviceBLCO | None = None,
-                 owns_device: bool = True, kernel: str = "xla",
-                 interpret: bool = True):
+                 owns_device: bool = True, kernel: str = "xla"):
         validate_kernel(kernel)
         self.dims = blco.dims
         self.resolution = resolution
@@ -58,7 +57,7 @@ class InMemoryPlan:
         self.kernel = kernel
         self._owns_device = owns_device if device is not None else True
         self._dev: DeviceBLCO | None = device if device is not None \
-            else DeviceBLCO(blco, kernel=kernel, interpret=interpret)
+            else DeviceBLCO(blco, kernel=kernel)
         self._stats = EngineStats(backend=self.backend)
         # kept for the analytic device-traffic model the ledger attributes
         self._nnz = blco.nnz
@@ -143,7 +142,7 @@ class StreamedPlan:
                  spec: ReservationSpec | None = None,
                  chunks: list | None = None,
                  resolution: str = "auto", copies: int = DEFAULT_COPIES,
-                 kernel: str = "xla", interpret: bool = True):
+                 kernel: str = "xla"):
         validate_kernel(kernel)
         self.blco = blco
         self.dims = blco.dims
@@ -151,7 +150,6 @@ class StreamedPlan:
         self.resolution = resolution
         self.copies = copies
         self.kernel = kernel
-        self.interpret = interpret
         self.spec = spec if spec is not None \
             else reservation_for(blco, reservation_nnz)
         # chunks are padded LAZILY, one launch per pull inside the streaming
@@ -173,8 +171,7 @@ class StreamedPlan:
                 resolution=resolution if resolution is not None
                 else self.resolution,
                 copies=copies if copies is not None else self.copies,
-                stats=self._stats, kernel=self.kernel,
-                interpret=self.interpret)
+                stats=self._stats, kernel=self.kernel)
 
     def device_bytes(self) -> int:
         """Reservation bytes in flight (the only device-resident state)."""
@@ -206,14 +203,14 @@ def sharded_bytes(blco: BLCOTensor, mesh, *, data_axis="data") -> int:
     total resident footprint is the padded arrays times that replication
     factor.
     """
+    from repro.core.distributed import shard_bytes
+    if not blco.nnz:
+        return 0
     data_size = 1
     for ax in (data_axis if isinstance(data_axis, tuple) else (data_axis,)):
         data_size *= mesh.shape[ax]
-    per = -(-blco.nnz // data_size) if blco.nnz else 0
-    padded = per * data_size
     replicas = mesh.size // data_size
-    return padded * (4 + 4 + blco.values.dtype.itemsize
-                     + 4 * blco.order) * replicas
+    return shard_bytes(blco, data_size) * data_size * replicas
 
 
 class ShardedPlan:
@@ -255,6 +252,17 @@ class ShardedPlan:
 
     def device_bytes(self) -> int:
         return 0 if self._closed else self._device_bytes
+
+    def bytes_by_device(self) -> dict:
+        """Resident nnz-array bytes on each device of the mesh."""
+        out: dict = {}
+        if self._run is None:
+            return out
+        for arr in self._run.device_shards.values():
+            for shard in arr.addressable_shards:
+                out[shard.device] = out.get(shard.device, 0) \
+                    + shard.data.nbytes
+        return out
 
     def stats(self) -> EngineStats:
         return self._stats

@@ -4,16 +4,17 @@
                    in — multi-device capacity, route through
                    core.distributed;
     in_memory      the tensor's true device footprint (hi + lo + vals +
-                   bases, padded) plus the rank-R factor working set fits
-                   the budget — the paper's in-memory regime, zero
-                   per-iteration H2D;
+                   bases, padded) plus the rank-R factor working set and
+                   one scan step's temporaries fits the budget — the
+                   paper's in-memory regime, zero per-iteration H2D;
     disk_streamed  the tensor exceeds the HOST budget
                    (``host_budget_bytes``) — spill it to the persistent
                    store and stream mmap'd reservation chunks straight to
                    the device (one tier below the paper's OOM regime);
     streamed       otherwise — fixed reservations stream the host-resident
                    tensor (the paper's out-of-memory regime), provided the
-                   in-flight reservation + factor working set fits;
+                   in-flight reservation + scan temporaries + factor
+                   working set fits;
     baselines      never auto-selected; request ``backend="coo"|"fcoo"|
                    "csf"`` explicitly for benchmark parity.
 
@@ -34,7 +35,7 @@ from repro.dist.context import get_mesh
 from repro.faults import inject as faults
 from repro.obs import trace as obs_trace
 
-from .api import factor_bytes, in_memory_bytes
+from .api import accumulator_itemsize, factor_bytes, in_memory_bytes
 from .plans import (BASELINE_KINDS, BaselinePlan, InMemoryPlan, ShardedPlan,
                     StreamedPlan, sharded_bytes)
 
@@ -47,7 +48,7 @@ def plan_for(blco: BLCOTensor, device_budget_bytes: int, *, rank: int,
              queues: int = 4, reservation_nnz: int | None = None,
              tensor=None, resolution: str = "auto",
              copies: int = DEFAULT_COPIES, kernel: str = "xla",
-             interpret: bool = True, host_budget_bytes: int | None = None,
+             host_budget_bytes: int | None = None,
              store_path: str | None = None, sanitize: bool | None = None):
     """Build the ExecutionPlan for ``blco`` under ``device_budget_bytes``.
 
@@ -55,8 +56,8 @@ def plan_for(blco: BLCOTensor, device_budget_bytes: int, *, rank: int,
     backends; without it the coordinates are decoded from the BLCO copy.
     ``kernel`` selects the compute path for the in-memory and streamed
     regimes: ``"xla"`` (reference dataflow, scan over the launch cache) or
-    ``"pallas"`` (fused single-``pallas_call`` pipeline; ``interpret=False``
-    on a real TPU).
+    ``"pallas"`` (fused single-``pallas_call`` pipeline, interpreted off
+    the TPU; it does not compile for TPU and raises there).
 
     ``host_budget_bytes`` extends the regime decision one memory tier
     down: when the tensor's host footprint (``format_bytes``) exceeds it,
@@ -80,7 +81,7 @@ def plan_for(blco: BLCOTensor, device_budget_bytes: int, *, rank: int,
             backend=backend, mesh=mesh, queues=queues,
             reservation_nnz=reservation_nnz, tensor=tensor,
             resolution=resolution, copies=copies, kernel=kernel,
-            interpret=interpret, host_budget_bytes=host_budget_bytes,
+            host_budget_bytes=host_budget_bytes,
             store_path=store_path)
         sp.set(backend=plan.backend)
         return wrap_plan(plan, enable=sanitize)
@@ -89,7 +90,7 @@ def plan_for(blco: BLCOTensor, device_budget_bytes: int, *, rank: int,
 def _plan_for_impl(blco: BLCOTensor, device_budget_bytes: int, *, rank: int,
                    dtype, backend: str, mesh, queues: int,
                    reservation_nnz: int | None, tensor, resolution: str,
-                   copies: int, kernel: str, interpret: bool,
+                   copies: int, kernel: str,
                    host_budget_bytes: int | None, store_path: str | None):
     if backend not in AUTO_BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; "
@@ -103,6 +104,7 @@ def _plan_for_impl(blco: BLCOTensor, device_budget_bytes: int, *, rank: int,
             if tensor is not None else BaselinePlan.from_blco(blco, backend)
 
     working = factor_bytes(blco.dims, rank, dtype)
+    acc_itemsize = accumulator_itemsize(blco.values.dtype, dtype)
     mesh = mesh if mesh is not None else get_mesh()
     if backend == "sharded" or (backend == "auto" and mesh is not None):
         if mesh is None:
@@ -134,30 +136,34 @@ def _plan_for_impl(blco: BLCOTensor, device_budget_bytes: int, *, rank: int,
         # (the plan.alloc fault probe fires inside LaunchCache.from_blco —
         # the regime's actual device-allocation moment)
         return InMemoryPlan(blco, resolution=resolution, copies=copies,
-                            kernel=k, interpret=interpret)
+                            kernel=k)
+
+    def _stream_need(spec):
+        return (spec.bytes_in_flight(queues)
+                + spec.temp_bytes(rank, acc_itemsize) + working)
 
     def _build_streamed(k):
         faults.maybe_fail("plan.alloc")
         spec = reservation_for(blco, reservation_nnz)
-        if spec.bytes_in_flight(queues) + working > device_budget_bytes:
+        if _stream_need(spec) > device_budget_bytes:
             raise ValueError(
                 f"no regime fits the budget: streaming needs "
-                f"{spec.bytes_in_flight(queues) + working} B in flight "
-                f"(reservation {spec.nnz} nnz x {queues} queues + factors) "
-                f"but the device budget is {device_budget_bytes} B")
+                f"{_stream_need(spec)} B in flight "
+                f"(reservation {spec.nnz} nnz x {queues} queues + scan "
+                f"temporaries + factors) but the device budget is "
+                f"{device_budget_bytes} B")
         return StreamedPlan(blco, queues=queues, spec=spec,
-                            resolution=resolution, copies=copies,
-                            kernel=k, interpret=interpret)
+                            resolution=resolution, copies=copies, kernel=k)
 
     def _build_disk(k):
         from repro.store import DiskStreamedPlan
         spec = reservation_for(blco, reservation_nnz)
-        if spec.bytes_in_flight(queues) + working > device_budget_bytes:
+        if _stream_need(spec) > device_budget_bytes:
             raise ValueError(
-                f"disk-streamed plan needs "
-                f"{spec.bytes_in_flight(queues) + working} B in flight "
-                f"(reservation {spec.nnz} nnz x {queues} queues + factors) "
-                f"but the device budget is {device_budget_bytes} B")
+                f"disk-streamed plan needs {_stream_need(spec)} B in flight "
+                f"(reservation {spec.nnz} nnz x {queues} queues + scan "
+                f"temporaries + factors) but the device budget is "
+                f"{device_budget_bytes} B")
         if store_path is None:
             fd, path = tempfile.mkstemp(suffix=".blco")
             os.close(fd)
@@ -168,7 +174,7 @@ def _plan_for_impl(blco: BLCOTensor, device_budget_bytes: int, *, rank: int,
             return DiskStreamedPlan.spill(
                 blco, path, reservation_nnz=spec.nnz, delete_on_close=delete,
                 queues=queues, resolution=resolution, copies=copies,
-                kernel=k, interpret=interpret)
+                kernel=k)
         except BaseException:
             if delete:              # don't orphan the anonymous spill file
                 try:
@@ -188,12 +194,13 @@ def _plan_for_impl(blco: BLCOTensor, device_budget_bytes: int, *, rank: int,
     # kernel fallback (pallas -> xla) but never change regime — the caller
     # asked for that tier by name.
     auto = backend == "auto"
-    if backend == "in_memory" or (auto and in_memory_bytes(blco) + working
+    in_memory_need = in_memory_bytes(blco, rank, dtype) + working
+    if backend == "in_memory" or (auto and in_memory_need
                                   <= device_budget_bytes):
-        if in_memory_bytes(blco) + working > device_budget_bytes:
+        if in_memory_need > device_budget_bytes:
             raise ValueError(
-                f"in-memory plan needs {in_memory_bytes(blco) + working} B "
-                f"resident (tensor + factors) but the device budget is "
+                f"in-memory plan needs {in_memory_need} B (resident tensor "
+                f"+ scan temporaries + factors) but the device budget is "
                 f"{device_budget_bytes} B")
         try:
             return _done(_kernel_fallback(_build_in_memory, kernel,
@@ -238,14 +245,12 @@ class DefaultEngine:
 
     def __init__(self, *, queues: int = 4, mesh=None, backend: str = "auto",
                  reservation_nnz: int | None = None, kernel: str = "xla",
-                 interpret: bool = True,
                  host_budget_bytes: int | None = None):
         self.queues = queues
         self.mesh = mesh
         self.backend = backend
         self.reservation_nnz = reservation_nnz
         self.kernel = kernel
-        self.interpret = interpret
         self.host_budget_bytes = host_budget_bytes
 
     def plan(self, blco: BLCOTensor, *, device_budget_bytes: int, rank: int,
@@ -254,5 +259,5 @@ class DefaultEngine:
                         backend=self.backend, mesh=self.mesh,
                         queues=self.queues,
                         reservation_nnz=self.reservation_nnz,
-                        kernel=self.kernel, interpret=self.interpret,
+                        kernel=self.kernel,
                         host_budget_bytes=self.host_budget_bytes)

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .delinearize import interpret_mode
+
 
 def _hadamard(vals, gathered):
     partial = vals[:, None].astype(jnp.result_type(vals, gathered[0]))
@@ -65,10 +67,8 @@ def _segment_kernel(vals_ref, tgt_ref, *rest):
     seg_tgt_ref[...] = seg_tgt
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("tile", "interpret"))
-def mttkrp_segments(vals, tgt, gathered, *, tile: int = 256,
-                    interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("tile",))
+def mttkrp_segments(vals, tgt, gathered, *, tile: int = 256):
     """Fused hadamard + on-the-fly segmented reduction, per VMEM tile.
 
     vals: (T,) float; tgt: (T,) int32 (ALTO order, NOT sorted); gathered:
@@ -88,7 +88,7 @@ def mttkrp_segments(vals, tgt, gathered, *, tile: int = 256,
         out_specs=(vec, mat),
         out_shape=(jax.ShapeDtypeStruct((t,), jnp.int32),
                    jax.ShapeDtypeStruct((t, r), jnp.result_type(vals, gathered[0]))),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(vals, tgt, *gathered)
     return seg_tgt, seg_sums
 
@@ -112,10 +112,8 @@ def _stash_kernel(vals_ref, tgt_ref, *rest, out_rows):
                                 preferred_element_type=partial.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("out_rows", "tile", "interpret"))
-def mttkrp_stash(vals, tgt, gathered, *, out_rows: int, tile: int = 256,
-                 interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("out_rows", "tile"))
+def mttkrp_stash(vals, tgt, gathered, *, out_rows: int, tile: int = 256):
     """Hierarchical small-mode variant: full (out_rows, R) accumulated in a
     revisited VMEM output block across the sequential TPU grid.
 
@@ -135,5 +133,5 @@ def mttkrp_stash(vals, tgt, gathered, *, out_rows: int, tile: int = 256,
         out_specs=pl.BlockSpec((out_rows, r), lambda i: (0, 0)),  # revisited
         out_shape=jax.ShapeDtypeStruct((out_rows, r),
                                    jnp.result_type(vals, gathered[0])),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(vals, tgt, *gathered)

@@ -19,6 +19,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: exactly off the TPU.
+
+    Derived from the platform, never chosen by a caller, so a TPU never
+    times the interpreter and a backend without Mosaic still validates the
+    kernels.  Every kernel of this package asks here.
+    """
+    return jax.default_backend() != "tpu"
+
+
 def extract_field_words(hi, lo, shift: int, width: int):
     """One mode's field from (hi, lo) uint32 word pairs — 32-bit ops only.
 
@@ -52,14 +62,13 @@ def _kernel(hi_ref, lo_ref, bases_ref, out_ref, *, field_bits, field_shifts):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("field_bits", "field_shifts", "tile",
-                                    "interpret"))
+                   static_argnames=("field_bits", "field_shifts", "tile"))
 def delinearize(idx_hi, idx_lo, bases, *, field_bits: tuple,
-                field_shifts: tuple, tile: int = 1024, interpret: bool = True):
+                field_shifts: tuple, tile: int = 1024):
     """(T,) uint32 words + (T, N) int32 bases -> (T, N) int32 coordinates.
 
     T must be a multiple of ``tile`` (callers pad launches to power-of-two
-    sizes already). interpret=True validates on CPU; on TPU pass False.
+    sizes already).
     """
     t = idx_hi.shape[0]
     n_modes = len(field_bits)
@@ -76,5 +85,5 @@ def delinearize(idx_hi, idx_lo, bases, *, field_bits: tuple,
         ],
         out_specs=pl.BlockSpec((tile, n_modes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, n_modes), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(idx_hi, idx_lo, bases)

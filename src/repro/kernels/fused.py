@@ -26,10 +26,16 @@ Inputs come straight from the device-resident launch cache
 (``repro.core.launches.LaunchCache.flat()``): no per-call host padding, and
 the host issues exactly ONE jitted dispatch per MTTKRP call.
 
-``interpret`` defaults to True (CPU validation container); pass False on a
-real TPU/GPU backend.  The factor matrices are passed as whole revisited
-blocks, so on TPU they must fit VMEM alongside one tile — the same
-constraint the paper's shared-memory gather stage has.
+The factor matrices are passed as whole revisited blocks, so on TPU they
+must fit VMEM alongside one tile — the same constraint the paper's
+shared-memory gather stage has.
+
+Mosaic does not compile this kernel: the in-kernel factor-row gather
+(``jnp.take`` of a ``(T,)`` index vector from an ``(I, R)`` factor block)
+is refused with "Shape mismatch in input, indices and output", at every
+width.  Making it compile needs the factors kept in HBM with the block's
+row slices copied in by DMA, so until then the fused path runs only where
+Pallas interprets and raises on a TPU (:func:`require_fused_support`).
 """
 from __future__ import annotations
 
@@ -43,11 +49,25 @@ from jax.experimental import pallas as pl
 from repro.core.counters import record_dispatch
 from repro.core.mttkrp import CONTENTION_THRESHOLD, choose_resolution
 
-from .delinearize import extract_field_words
+from .delinearize import extract_field_words, interpret_mode
 
 # VMEM budget for the stash variant: the revisited (out_rows, R) block must
 # fit alongside one tile (same bound ops.py used for the 3-dispatch path).
 STASH_MAX_ROWS = 4 * CONTENTION_THRESHOLD
+
+
+def require_fused_support() -> None:
+    """Raise where the fused kernel would have to compile for a TPU.
+
+    Mosaic refuses its in-kernel row gather (see the module docstring), and
+    running the interpreter on a TPU would time the interpreter, so the
+    fused path is refused up front rather than failing or crawling later.
+    """
+    if not interpret_mode():
+        raise NotImplementedError(
+            "kernel='pallas' does not compile for TPU: Mosaic refuses the "
+            "fused kernel's in-kernel factor-row gather ('Shape mismatch in "
+            "input, indices and output'); use kernel='xla'")
 
 
 def _delinearize_tile(hi, lo, bases, field_bits, field_shifts):
@@ -180,8 +200,7 @@ def _variant_for(resolution: str, out_rows: int) -> str:
 
 def fused_mttkrp_flat(hi, lo, vals, bases, factors, *, field_bits: tuple,
                       field_shifts: tuple, mode: int, out_rows: int,
-                      resolution: str = "auto", tile: int = 256,
-                      interpret: bool = True):
+                      resolution: str = "auto", tile: int = 256):
     """Fused MTTKRP over a flat reservation-padded nnz stream.
 
     hi/lo: (T,) uint32; vals: (T,); bases: (T, N) int32; T is the padded
@@ -189,6 +208,7 @@ def fused_mttkrp_flat(hi, lo, vals, bases, factors, *, field_bits: tuple,
     the block offsets, and segments are discovered per tile).  Exactly one
     recorded dispatch.
     """
+    require_fused_support()
     factors = tuple(jnp.asarray(f) for f in factors)
     if resolution == "auto":
         resolution = choose_resolution(out_rows)
@@ -202,12 +222,11 @@ def fused_mttkrp_flat(hi, lo, vals, bases, factors, *, field_bits: tuple,
                        field_shifts=field_shifts, mode=mode,
                        out_rows=out_rows,
                        variant=_variant_for(resolution, out_rows),
-                       tile=tile, interpret=interpret)
+                       tile=tile, interpret=interpret_mode())
 
 
 def fused_cache_mttkrp(cache, factors, mode: int, *,
-                       resolution: str = "auto", tile: int = 256,
-                       interpret: bool = True):
+                       resolution: str = "auto", tile: int = 256):
     """Fused MTTKRP straight from a device-resident ``LaunchCache``.
 
     Zero per-call host work: the cache's stacked ``(L, reservation)`` arrays
@@ -226,5 +245,4 @@ def fused_cache_mttkrp(cache, factors, mode: int, *,
                              field_bits=cache.re_fields,
                              field_shifts=cache.re_shifts, mode=mode,
                              out_rows=cache.dims[mode],
-                             resolution=resolution, tile=tile,
-                             interpret=interpret)
+                             resolution=resolution, tile=tile)

@@ -14,8 +14,6 @@ delinearize kernel -> XLA gather -> compute kernel, each phase round-
 tripping through HBM) as the benchmark reference the fused path is
 measured against in ``BENCH_3.json``.  It too is cache-driven — the host
 numpy padding it used to redo every call is gone.
-
-``interpret`` defaults to True (CPU validation container); pass False on TPU.
 """
 from __future__ import annotations
 
@@ -34,8 +32,7 @@ from .ref import scatter_segments_ref
 
 
 def pallas_mttkrp(blco: BLCOTensor, factors, mode: int, *,
-                  tile: int = 256, interpret: bool = True,
-                  resolution: str = "auto", cache=None):
+                  tile: int = 256, resolution: str = "auto", cache=None):
     """Full mode-n MTTKRP, fused single-dispatch Pallas path.
 
     The launch cache is built once (attached to ``blco``, or passed in);
@@ -44,12 +41,12 @@ def pallas_mttkrp(blco: BLCOTensor, factors, mode: int, *,
     assert 0 <= mode < blco.order
     cache = cache if cache is not None else launch_cache_for(blco)
     return fused_cache_mttkrp(cache, factors, mode, resolution=resolution,
-                              tile=tile, interpret=interpret)
+                              tile=tile)
 
 
 def pallas_mttkrp_phases(blco: BLCOTensor, factors, mode: int, *,
-                         tile: int = 256, interpret: bool = True,
-                         resolution: str = "auto", cache=None):
+                         tile: int = 256, resolution: str = "auto",
+                         cache=None):
     """The PR-2 three-phase Pallas pipeline (benchmark reference).
 
     Per call: delinearize kernel -> HBM coords -> XLA gather -> HBM rows ->
@@ -76,8 +73,7 @@ def pallas_mttkrp_phases(blco: BLCOTensor, factors, mode: int, *,
 
     # phase 1: processing (standalone Pallas delinearize kernel)
     coords = delinearize(hi, lo, bases, field_bits=cache.re_fields,
-                         field_shifts=cache.re_shifts, tile=tile,
-                         interpret=interpret)
+                         field_shifts=cache.re_shifts, tile=tile)
     # phase 2: gather non-target rows (XLA native gather, HBM round-trip)
     gathered = tuple(jnp.take(factors[m], coords[:, m], axis=0)
                      for m in range(blco.order) if m != mode)
@@ -86,9 +82,8 @@ def pallas_mttkrp_phases(blco: BLCOTensor, factors, mode: int, *,
     # phase 3: computing (Pallas kernel) + final update
     if use_stash:
         return mttkrp_stash(vals, tgt, gathered, out_rows=blco.dims[mode],
-                            tile=tile, interpret=interpret)
-    seg_tgt, seg_sums = mttkrp_segments(vals, tgt, gathered, tile=tile,
-                                        interpret=interpret)
+                            tile=tile)
+    seg_tgt, seg_sums = mttkrp_segments(vals, tgt, gathered, tile=tile)
     return scatter_segments_ref(seg_tgt, seg_sums, blco.dims[mode])
 
 

@@ -11,15 +11,20 @@ touches jax device state (dry-run sets XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
-from repro.dist import compat
+import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(4, 2), axes=("data", "model")):
     """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
-    return compat.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)

@@ -182,8 +182,7 @@ def moe_apply_sharded(p, cfg, x, mesh):
         aux = jax.lax.pmean(aux, dp)                 # replicate for out_spec
         return combined.reshape(bl, s, d), aux
 
-    from repro.dist.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         block, mesh=mesh,
         in_specs=(xspec, rspec, wspec_in, wspec_in, wspec_out),
         out_specs=(xspec, P()),

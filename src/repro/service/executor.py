@@ -5,7 +5,7 @@ pools back the plans it hands out:
 
 * **reservation pool** — jobs whose tensors pad to the same
   ``ReservationSpec`` stream through identical device buffer shapes, so
-  they hit the same compiled ``launch_mttkrp`` executable and the budget is
+  they hit the same compiled ``stacked_mttkrp`` executable and the budget is
   charged once per pooled shape (the paper's reused queue reservations,
   shared across tenants).  Both streaming *tiers* join this pool: host-
   resident tensors (``PooledStreamedPlan``) and spilled, disk-resident
@@ -32,10 +32,11 @@ import dataclasses
 
 import jax.numpy as jnp
 
+from repro.core.launches import launch_cache_bytes
 from repro.core.mttkrp import DeviceBLCO
 from repro.core.streaming import ReservationSpec
 from repro.faults import inject as faults
-from repro.engine.api import factor_bytes, in_memory_bytes
+from repro.engine.api import factor_bytes
 from repro.engine.plans import InMemoryPlan, StreamedPlan
 from repro.obs import ledger as obs_ledger
 from repro.store import DiskStreamedPlan
@@ -165,7 +166,7 @@ class ServiceEngine:
         """Bytes a device-resident plan for this handle would newly hold."""
         if handle.key in self._resident_pool:
             return 0
-        return in_memory_bytes(handle.blco)
+        return launch_cache_bytes(handle.blco)
 
     def min_cost(self, handle: TensorHandle, rank: int, dtype=jnp.float32) -> int:
         """Cheapest unpooled device need (the can-never-fit check).
@@ -179,7 +180,7 @@ class ServiceEngine:
         stream = handle.spec.bytes_in_flight(self.queues)
         if not handle.resident:
             return working + stream
-        return working + min(stream, in_memory_bytes(handle.blco))
+        return working + min(stream, launch_cache_bytes(handle.blco))
 
     # ---------------------------------------------------------------- plans
     def try_plan(self, handle: TensorHandle, *, rank: int,

@@ -42,7 +42,7 @@ from repro.obs import trace as obs_trace
 class BuildParams:
     """BLCO construction parameters (see ``core.build_blco``)."""
     target_bits: int = 64
-    max_nnz_per_block: int = 1 << 27
+    max_nnz_per_block: int | None = None     # None: derived from the device
     launch_nnz_budget: int | None = None
 
 
@@ -124,8 +124,8 @@ class TensorHandle:
             raise RuntimeError(
                 f"tensor {self.key} is spilled to disk; load() it before "
                 f"planning a device-resident copy")
-        from repro.engine.api import in_memory_bytes
-        return in_memory_bytes(self.blco)
+        from repro.core.launches import launch_cache_bytes
+        return launch_cache_bytes(self.blco)
 
     def open_stored(self):
         """Open the persistent copy for disk-streaming (caller closes)."""

@@ -37,7 +37,7 @@ class DiskStreamedPlan:
     def __init__(self, stored: StoredBLCO | str | os.PathLike, *,
                  queues: int = 4, resolution: str = "auto",
                  copies: int = DEFAULT_COPIES, kernel: str = "xla",
-                 interpret: bool = True, spec: ReservationSpec | None = None,
+                 spec: ReservationSpec | None = None,
                  delete_on_close: bool = False):
         validate_kernel(kernel)
         if not isinstance(stored, StoredBLCO):
@@ -48,7 +48,6 @@ class DiskStreamedPlan:
         self.resolution = resolution
         self.copies = copies
         self.kernel = kernel
-        self.interpret = interpret
         self.spec = spec if spec is not None else stored.spec
         self.delete_on_close = delete_on_close
         self._stats = EngineStats(backend=self.backend)
@@ -79,8 +78,7 @@ class DiskStreamedPlan:
                 resolution=resolution if resolution is not None
                 else self.resolution,
                 copies=copies if copies is not None else self.copies,
-                stats=self._stats, kernel=self.kernel,
-                interpret=self.interpret)
+                stats=self._stats, kernel=self.kernel)
 
     def device_bytes(self) -> int:
         """Reservation bytes in flight (identical to the streamed regime)."""

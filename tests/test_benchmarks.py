@@ -71,7 +71,8 @@ def test_bench_multitenant_json_schema(tmp_path):
 
 def test_bench_oom_json_schema(tmp_path):
     """The memory-hierarchy bench writes its store to the given dir and
-    emits bit-identical per-tier timings + the bounded-window ratio."""
+    emits per-tier timings (outputs equal to f32 rounding) + the
+    bounded-window ratio."""
     path = tmp_path / "BENCH_5.json"
     store = tmp_path / "store"
     store.mkdir()
@@ -168,3 +169,25 @@ def test_committed_bench3_shows_speedup():
     assert payload["geomean_speedup_cached_scan_vs_per_launch_loop"] >= 2.0
     for name, s in payload["suites"].items():
         assert s["dispatches_per_call_cached"] == 1, name
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_set):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR wins and
+    nothing is set in code; otherwise one fixed path in the checkout."""
+    import jax
+    from repro.core.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_set:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(REPO, ".jax_cache")
+            assert enable_compile_cache() == want
+            assert enable_compile_cache() == want        # fixed, not fresh
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from repro import core
+from repro.core.launches import launch_cache_bytes
 from repro.engine import (BASELINE_KINDS, BaselinePlan, DefaultEngine,
                           ExecutionPlan, InMemoryPlan, StreamedPlan,
                           factor_bytes, in_memory_bytes, plan_for)
@@ -39,7 +40,7 @@ def test_plan_for_auto_selects_regime_and_matches_oracle():
     t = _tensor()
     b = core.build_blco(t, max_nnz_per_block=256)
     factors = _factors(t.dims, 8)
-    fits = in_memory_bytes(b) + factor_bytes(t.dims, 8, np.float32)
+    fits = in_memory_bytes(b, 8) + factor_bytes(t.dims, 8, np.float32)
 
     big = plan_for(b, fits, rank=8)                   # exactly fits
     small = plan_for(b, fits - 1, rank=8, queues=2)   # one byte short
@@ -63,9 +64,12 @@ def test_plan_device_bytes_and_close():
     # exact resident footprint: hi + lo + vals + bases, 256-lane padded
     padded = -(-b.nnz // 256) * 256
     assert plan.device_bytes() == padded * (4 + 4 + 4 + 4 * b.order)
-    assert plan.device_bytes() == in_memory_bytes(b)
+    assert plan.device_bytes() == launch_cache_bytes(b)
+    # the regime's need adds one scan step's temporaries to the resident
+    # bytes; they are transient, so the plan never holds them
+    assert in_memory_bytes(b, 8) > plan.device_bytes()
     freed = plan.close()
-    assert freed == in_memory_bytes(b) and plan.device_bytes() == 0
+    assert freed == launch_cache_bytes(b) and plan.device_bytes() == 0
     assert plan.close() == 0                          # idempotent
     with pytest.raises(RuntimeError, match="closed"):
         plan.mttkrp(_factors(t.dims, 8), 0)
@@ -151,7 +155,7 @@ def test_default_engine_protocol():
     plan = eng.plan(b, device_budget_bytes=1 << 30, rank=6)
     assert plan.backend == "in_memory"
     plan.close()
-    fits = in_memory_bytes(b) + factor_bytes(b.dims, 6, np.float32)
+    fits = in_memory_bytes(b, 6) + factor_bytes(b.dims, 6, np.float32)
     plan = eng.plan(b, device_budget_bytes=fits - 1, rank=6)
     assert plan.backend == "streamed"
     plan.close()
@@ -185,11 +189,18 @@ def test_sharded_plan_via_mesh_context():
         plan = plan_for(b, 1 << 30, rank=8)
         assert plan.backend == "sharded", plan.backend
         # nnz arrays shard over data (4) and replicate over model (2):
-        # footprint counts every model-axis replica
-        per = -(-b.nnz // 4)
+        # footprint counts every model-axis replica; each shard pads to a
+        # size class so the scan step cuts it evenly
+        from repro.core.padding import pad_bucket
+        per = pad_bucket(-(-b.nnz // 4))
         assert plan.device_bytes() == per * 4 * (4 + 4 + 4 + 4 * 3) * 2
         from repro.engine import sharded_bytes
         assert plan.device_bytes() == sharded_bytes(b, plan.mesh)
+        # every device holds one data shard (its model-axis replica)
+        per_dev = plan.bytes_by_device()
+        assert len(per_dev) == 8
+        assert set(per_dev.values()) == {per * (4 + 4 + 4 + 4 * 3)}
+        assert sum(per_dev.values()) == plan.device_bytes()
         # an undersized budget is rejected before any device upload
         try:
             plan_for(b, plan.device_bytes() // 2, rank=8)

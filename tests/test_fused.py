@@ -3,11 +3,10 @@
 Satellite coverage (ISSUE 3): orders 3-5, both conflict resolutions
 (``register`` -> segment variant, ``hierarchical`` -> stash variant on the
 short-mode tensors), ragged nnz counts that exercise the reservation
-padding slots, and both interpret and compiled configurations (compiled
-runs only where a Pallas-capable backend exists; the CPU container
-validates through the interpreter).
+padding slots.  Off the TPU the kernel runs in the Pallas interpreter;
+on a TPU it is refused (Mosaic does not compile its row gather, see
+``tests/test_tpu_compile.py``).
 """
-import jax
 import numpy as np
 import pytest
 
@@ -25,8 +24,6 @@ CASES = [
     ((128, 4, 256, 8, 3), 801, 16, 128),       # order 5
 ]
 
-COMPILED_OK = jax.default_backend() in ("tpu", "gpu")
-
 
 def _factors(dims, rank=8, seed=0):
     rng = np.random.default_rng(seed)
@@ -38,23 +35,15 @@ def _rel_err(a, oracle):
         (np.max(np.abs(oracle)) + 1e-30)
 
 
-@pytest.mark.parametrize("interpret", [
-    True,
-    pytest.param(False, marks=pytest.mark.skipif(
-        not COMPILED_OK,
-        reason="compiled pallas_call needs a TPU/GPU backend")),
-])
 @pytest.mark.parametrize("resolution", ["register", "hierarchical"])
 @pytest.mark.parametrize("dims,nnz,tb,mx", CASES)
-def test_fused_matches_oracle_all_modes(dims, nnz, tb, mx, resolution,
-                                        interpret):
+def test_fused_matches_oracle_all_modes(dims, nnz, tb, mx, resolution):
     t = core.random_tensor(dims, nnz, seed=7, dist="powerlaw")
     b = core.build_blco(t, target_bits=tb, max_nnz_per_block=mx)
     factors = _factors(dims)
     for mode in range(t.order):
         oracle = core.mttkrp_dense_oracle(t, factors, mode)
-        out = pallas_mttkrp(b, factors, mode, resolution=resolution,
-                            interpret=interpret)
+        out = pallas_mttkrp(b, factors, mode, resolution=resolution)
         assert _rel_err(out, oracle) < 5e-4, (mode, resolution)
 
 
@@ -125,3 +114,25 @@ def test_fused_padding_slots_are_exact():
         assert _rel_err(c, oracle) < 5e-4, mode
     tight.delete()
     loose.delete()
+
+
+def test_pallas_kernel_refused_where_it_cannot_compile(monkeypatch):
+    """On a TPU the fused kernel would have to compile, and Mosaic refuses
+    it: every entry point raises up front instead of running the
+    interpreter there or failing deep inside a plan."""
+    import repro.kernels.fused as fused
+    from repro.engine import plan_for
+    t = core.random_tensor((70, 40, 30), 500, seed=7, dist="powerlaw")
+    b = core.build_blco(t, target_bits=12, max_nnz_per_block=512)
+    factors = _factors(t.dims)
+    monkeypatch.setattr(fused, "interpret_mode", lambda: False)
+    for backend in ("in_memory", "streamed"):
+        with pytest.raises(NotImplementedError, match="does not compile"):
+            plan_for(b, 1 << 40, rank=8, backend=backend, kernel="pallas")
+    with pytest.raises(NotImplementedError, match="does not compile"):
+        pallas_mttkrp(b, factors, 0)
+    # the XLA kernel is unaffected
+    plan = plan_for(b, 1 << 40, rank=8, backend="in_memory")
+    oracle = core.mttkrp_dense_oracle(t, factors, 0)
+    assert _rel_err(plan.mttkrp(factors, 0), oracle) < 5e-4
+    plan.close()

@@ -99,3 +99,30 @@ def test_tns_roundtrip(tmp_path):
     t2 = tz.load_tns(str(p))
     assert t2.nnz == t.nnz
     np.testing.assert_allclose(t2.to_dense(), t.to_dense(), rtol=1e-12)
+
+
+@pytest.mark.parametrize("dist", ["powerlaw", "uniform"])
+def test_random_tensor_exact_has_exactly_nnz_distinct(dist):
+    """The exact generator reaches the requested nnz with distinct, sorted
+    coordinates (power-law draws repeat, so it tops up), and the same seed
+    gives the same tensor."""
+    dims, nnz = (120, 90, 280), 60_000
+    t = tz.random_tensor_exact(dims, nnz, seed=3, dist=dist)
+    assert t.nnz == nnz and t.dims == dims
+    keys = np.ravel_multi_index(t.indices.T, dims)
+    assert np.all(np.diff(keys) > 0)                  # distinct, lex order
+    assert np.all(t.values != 0)
+    assert np.all(t.indices >= 0) and np.all(t.indices < np.array(dims))
+    again = tz.random_tensor_exact(dims, nnz, seed=3, dist=dist)
+    np.testing.assert_array_equal(again.indices, t.indices)
+    np.testing.assert_array_equal(again.values, t.values)
+    if dist == "powerlaw":
+        # the same draws deduplicated by random_tensor fall short of nnz
+        assert tz.random_tensor(dims, nnz, seed=3, dist=dist).nnz < nnz
+
+
+def test_frostt_like_keeps_published_dims():
+    dims, full, _ = tz.FROSTT_SUITE["nell-2"]
+    assert dims == (12092, 9184, 28818) and full == 76_879_419
+    t = tz.frostt_like("nell-2", seed=0, nnz=5_000)
+    assert t.dims == dims and t.nnz == 5_000

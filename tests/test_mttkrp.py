@@ -109,9 +109,12 @@ def test_direct_resolution_matches_oracle_all_modes():
        seed=st.integers(min_value=0, max_value=31))
 @settings(max_examples=12, deadline=None)
 def test_launch_zero_padding_exact_all_resolutions(dims, nnz, seed):
-    """Property: padding launches to the reservation size is EXACT for all
-    three resolutions — pad slots delinearize to coordinate 0 with value 0,
-    so padded and unpadded runs are bit-identical."""
+    """Property: padding launches to the reservation size is exact for all
+    three resolutions — pad slots delinearize to coordinate 0 with value 0
+    and add exact zeros.  The padded and the exact launch shapes compile to
+    different programs, whose segment and scatter sums XLA may associate
+    differently, so the two agree to f32 rounding: within 1e-6 of the
+    largest output (about 8 ulp), not bit for bit."""
     t = core.random_tensor(dims, nnz, seed=seed, dist="powerlaw")
     b = core.build_blco(t, target_bits=10, max_nnz_per_block=64)
     rng = np.random.default_rng(seed)
@@ -120,8 +123,10 @@ def test_launch_zero_padding_exact_all_resolutions(dims, nnz, seed):
         for res in ("register", "hierarchical", "direct"):
             padded = core.mttkrp(b, factors, mode, resolution=res, pad=True)
             exact = core.mttkrp(b, factors, mode, resolution=res, pad=False)
-            np.testing.assert_array_equal(np.asarray(padded),
-                                          np.asarray(exact), err_msg=res)
+            exact = np.asarray(exact)
+            np.testing.assert_allclose(
+                np.asarray(padded), exact, rtol=0,
+                atol=1e-6 * np.max(np.abs(exact)), err_msg=res)
 
 
 def test_fp64_path():

@@ -125,6 +125,7 @@ def test_fast_path_and_streaming_share_one_budget():
     # both still match a solo engine run on the same seeds
     b = core.build_blco(t_big, max_nnz_per_block=256)
     solo = plan_for(b, h_big.spec.bytes_in_flight(2)
+                    + h_big.spec.temp_bytes(4, 4)
                     + factor_bytes(t_big.dims, 4, np.float32),
                     rank=4, queues=2)
     assert solo.backend == "streamed"

@@ -67,8 +67,8 @@ def test_callback_fixture_rejected():
     fs = audit_callbacks(closed, path="tests/fixture.py", symbol="bad")
     assert fs and all(f.pass_id == PASS_CALLBACK for f in fs)
     assert "host round-trip" in fs[0].message
-    # the walk found it inside the pjit sub-jaxpr, not at top level
-    assert "pjit" in fs[0].message
+    # the walk found it inside the jit sub-jaxpr, not at top level
+    assert "(at jit," in fs[0].message
 
 
 def test_callback_audit_clean_on_plain_math():

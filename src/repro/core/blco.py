@@ -12,10 +12,12 @@ mode-agnostic, exactly the property the paper is built around.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-import time
 
 import numpy as np
+
+from repro.obs import trace as obs_trace
 
 from . import linearize as lin
 from .padding import default_launch_nnz, device_memory_bytes
@@ -108,60 +110,74 @@ def build_blco(t: SparseTensor, *, target_bits: int = 64,
     if max_nnz_per_block is None:
         max_nnz_per_block = default_launch_nnz(
             t.order, t.values.dtype.itemsize, device_memory_bytes())
+    with obs_trace.span("blco.build", "blco", nnz=t.nnz, order=t.order):
+        return _build(t, target_bits, max_nnz_per_block,
+                      launch_nnz_budget or max_nnz_per_block)
+
+
+@contextlib.contextmanager
+def _phase(stats: dict, key: str):
+    """The ``blco.<key>`` span of one construction phase; its duration
+    becomes ``stats[key]`` (the same clock reads) when the phase ends."""
+    with obs_trace.timed(f"blco.{key}", "blco") as sp:
+        yield
+    stats[key] = sp.duration_s
+
+
+def _build(t: SparseTensor, target_bits: int, max_nnz_per_block: int,
+           budget: int) -> BLCOTensor:
     stats: dict[str, float] = {}
-    t0 = time.perf_counter()
-    spec = lin.LinearSpec.make(t.dims)
-    hi, lo = lin.alto_encode(spec, t.indices)
-    stats["linearize"] = time.perf_counter() - t0
+    with _phase(stats, "linearize"):
+        spec = lin.LinearSpec.make(t.dims)
+        hi, lo = lin.alto_encode(spec, t.indices)
 
-    t0 = time.perf_counter()
-    perm = lin.sort_by_alto(hi, lo)
-    hi, lo = hi[perm], lo[perm]
-    indices = t.indices[perm]
-    values = t.values[perm]
-    stats["sort"] = time.perf_counter() - t0
+    with _phase(stats, "sort"):
+        perm = lin.sort_by_alto(hi, lo)
+        hi, lo = hi[perm], lo[perm]
+        indices = t.indices[perm]
+        values = t.values[perm]
 
-    t0 = time.perf_counter()
-    re = lin.reencode_spec(spec, target_bits)
-    keys = lin.block_key(spec, re, hi, lo)
-    stats["block_keys"] = time.perf_counter() - t0
+    with _phase(stats, "block_keys"):
+        re = lin.reencode_spec(spec, target_bits)
+        keys = lin.block_key(spec, re, hi, lo)
 
-    t0 = time.perf_counter()
-    stored = lin.reencode(spec, re, indices)
-    idx_hi, idx_lo = split64(stored)
-    stats["reencode"] = time.perf_counter() - t0
+    with _phase(stats, "reencode"):
+        stored = lin.reencode(spec, re, indices)
+        idx_hi, idx_lo = split64(stored)
 
     # --- initial blocks: runs of equal key in sorted order -------------------
-    t0 = time.perf_counter()
-    nnz = values.shape[0]
-    blocks: list[Block] = []
-    if nnz:
-        boundaries = np.flatnonzero(np.concatenate(([True], keys[1:] != keys[:-1])))
-        boundaries = np.append(boundaries, nnz)
-        for s, e in zip(boundaries[:-1], boundaries[1:]):
-            key = int(keys[s])
-            upper = tuple(int(u) for u in lin.key_to_upper_coords(spec, re, key))
-            # split oversized blocks to the device budget (paper: 2^27 nnz)
-            for cs in range(int(s), int(e), max_nnz_per_block):
-                ce = min(cs + max_nnz_per_block, int(e))
-                blocks.append(Block(key=key, start=cs, end=ce, upper=upper))
-    stats["blocking"] = time.perf_counter() - t0
+    with _phase(stats, "blocking"):
+        nnz = values.shape[0]
+        blocks: list[Block] = []
+        if nnz:
+            boundaries = np.flatnonzero(
+                np.concatenate(([True], keys[1:] != keys[:-1])))
+            boundaries = np.append(boundaries, nnz)
+            for s, e in zip(boundaries[:-1], boundaries[1:]):
+                key = int(keys[s])
+                upper = tuple(int(u) for u in
+                              lin.key_to_upper_coords(spec, re, key))
+                # split oversized blocks to the device budget (paper: 2^27 nnz)
+                for cs in range(int(s), int(e), max_nnz_per_block):
+                    ce = min(cs + max_nnz_per_block, int(e))
+                    blocks.append(Block(key=key, start=cs, end=ce,
+                                        upper=upper))
 
     # --- batch small blocks into launches ------------------------------------
-    t0 = time.perf_counter()
-    budget = launch_nnz_budget or max_nnz_per_block
-    launches: list[Launch] = []
-    cur: list[int] = []
-    cur_nnz = 0
-    for i, b in enumerate(blocks):
-        if cur and cur_nnz + b.nnz > budget:
-            launches.append(Launch(tuple(cur), blocks[cur[0]].start, blocks[cur[-1]].end))
-            cur, cur_nnz = [], 0
-        cur.append(i)
-        cur_nnz += b.nnz
-    if cur:
-        launches.append(Launch(tuple(cur), blocks[cur[0]].start, blocks[cur[-1]].end))
-    stats["batching"] = time.perf_counter() - t0
+    with _phase(stats, "batching"):
+        launches: list[Launch] = []
+        cur: list[int] = []
+        cur_nnz = 0
+        for i, b in enumerate(blocks):
+            if cur and cur_nnz + b.nnz > budget:
+                launches.append(Launch(tuple(cur), blocks[cur[0]].start,
+                                       blocks[cur[-1]].end))
+                cur, cur_nnz = [], 0
+            cur.append(i)
+            cur_nnz += b.nnz
+        if cur:
+            launches.append(Launch(tuple(cur), blocks[cur[0]].start,
+                                   blocks[cur[-1]].end))
 
     return BLCOTensor(dims=t.dims, spec=spec, re=re, idx_hi=idx_hi, idx_lo=idx_lo,
                       values=values, blocks=blocks, launches=launches,

@@ -25,6 +25,8 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace as obs_trace
+
 
 @dataclasses.dataclass
 class CPResult:
@@ -124,36 +126,50 @@ def cp_als_step(mttkrp_fn, state: CPState) -> CPState:
     ``mttkrp_fn`` is an engine plan, any ``.mttkrp``-bearing backend, or a
     bare callable returning the (I_mode, R) MTTKRP result (``as_mttkrp_fn``).
     Returns ``state`` for chaining; a converged state is returned unchanged.
+
+    Spans (``repro.obs.trace``): ``als.sweep`` over the sweep, with
+    ``als.mttkrp`` and ``als.update`` per mode and ``als.fit`` over the
+    fit's device ops and its read-back.
     """
     if state.converged:
         return state
     mttkrp_fn = as_mttkrp_fn(mttkrp_fn)
-    n_modes = len(state.dims)
-    rank = state.rank
-    dtype = state.factors[0].dtype
-    factors, grams = state.factors, state.grams
-    m_mat = None
-    for n in range(n_modes):
-        m_mat = mttkrp_fn(factors, n)                    # line 4
-        factors[n], lam, grams[n] = sweep_mode_update(m_mat, grams, n)
-        state.lam = lam
-
-    # fit = 1 - ||X - X_hat||_F / ||X||_F, computed without materializing
-    # X_hat (standard CP-ALS identity; m_mat is the last mode's MTTKRP).
-    last = n_modes - 1
-    v_all = jnp.ones((rank, rank), dtype)
-    for m in range(n_modes):
-        v_all = v_all * grams[m]
-    norm_est_sq = state.lam @ (v_all @ state.lam)
-    inner = jnp.sum(state.lam * jnp.sum(m_mat * factors[last], axis=0))
-    resid_sq = jnp.maximum(state.norm_x ** 2 + norm_est_sq - 2.0 * inner, 0.0)
-    fit = float(1.0 - jnp.sqrt(resid_sq) / state.norm_x)
+    with obs_trace.span("als.sweep", "als", iteration=state.iteration):
+        fit = _sweep(mttkrp_fn, state)
     state.fits.append(fit)
     state.iteration += 1
     if abs(fit - state.prev_fit) < state.tol:
         state.converged = True
     state.prev_fit = fit
     return state
+
+
+def _sweep(mttkrp_fn, state: CPState) -> float:
+    """Every mode's update of ``state`` in place; the fit it reaches."""
+    n_modes = len(state.dims)
+    rank = state.rank
+    dtype = state.factors[0].dtype
+    factors, grams = state.factors, state.grams
+    m_mat = None
+    for n in range(n_modes):
+        with obs_trace.span("als.mttkrp", "als", mode=n):
+            m_mat = mttkrp_fn(factors, n)                # line 4
+        with obs_trace.span("als.update", "als", mode=n):
+            factors[n], lam, grams[n] = sweep_mode_update(m_mat, grams, n)
+            state.lam = lam
+
+    # fit = 1 - ||X - X_hat||_F / ||X||_F, computed without materializing
+    # X_hat (standard CP-ALS identity; m_mat is the last mode's MTTKRP).
+    with obs_trace.span("als.fit", "als"):
+        last = n_modes - 1
+        v_all = jnp.ones((rank, rank), dtype)
+        for m in range(n_modes):
+            v_all = v_all * grams[m]
+        norm_est_sq = state.lam @ (v_all @ state.lam)
+        inner = jnp.sum(state.lam * jnp.sum(m_mat * factors[last], axis=0))
+        resid_sq = jnp.maximum(state.norm_x ** 2 + norm_est_sq
+                               - 2.0 * inner, 0.0)
+        return float(1.0 - jnp.sqrt(resid_sq) / state.norm_x)
 
 
 def cp_als(mttkrp_fn, dims, rank, *, norm_x: float, iters: int = 25,

@@ -31,6 +31,7 @@ the thin single-tensor convenience wrapper.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 
@@ -81,6 +82,7 @@ class EngineStats:
     retries: int = 0             # transient failures retried successfully
     giveups: int = 0             # retry budgets exhausted (error surfaced)
     demotions: int = 0           # regime/kernel fallbacks the ladder took
+    compiles: int = 0            # backend compiles inside the plan's mttkrp
     hist: EngineHists = dataclasses.field(default_factory=EngineHists)
 
     @property
@@ -102,8 +104,19 @@ class EngineStats:
             "retries": self.retries,
             "giveups": self.giveups,
             "demotions": self.demotions,
+            "compiles": self.compiles,
             "hist": self.hist.snapshot(),
         }
+
+
+@contextlib.contextmanager
+def count_compiles(stats: EngineStats):
+    """Add the backend compiles made inside the block to ``stats``."""
+    before = obs_trace.compile_count()
+    try:
+        yield
+    finally:
+        stats.compiles += obs_trace.compile_count() - before
 
 
 # Deprecated name: the streaming layer's ad-hoc stats object predates the

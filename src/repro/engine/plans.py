@@ -28,7 +28,8 @@ from repro.obs import ledger as obs_ledger
 from repro.obs import trace as obs_trace
 from repro.core.mttkrp import DEFAULT_COPIES, DeviceBLCO, validate_kernel
 from repro.core.streaming import (EngineStats, LaunchChunks, ReservationSpec,
-                                  reservation_for, stream_mttkrp)
+                                  count_compiles, reservation_for,
+                                  stream_mttkrp)
 from repro.core.tensor import SparseTensor, from_coo
 
 from .api import in_memory_bytes
@@ -78,27 +79,26 @@ class InMemoryPlan:
         if self._dev is None:
             raise RuntimeError("plan is closed")
         with obs_trace.span("plan.mttkrp", "plan", backend=self.backend,
-                            mode=mode):
+                            mode=mode), count_compiles(self._stats):
             t0 = time.perf_counter()
-            out = self._dev.mttkrp(
-                factors, mode, kernel=self.kernel,
-                resolution=resolution if resolution is not None
-                else self.resolution,
-                copies=copies if copies is not None else self.copies)
+            with obs_trace.span("plan.issue", "plan", mode=mode):
+                out = self._dev.mttkrp(
+                    factors, mode, kernel=self.kernel,
+                    resolution=resolution if resolution is not None
+                    else self.resolution,
+                    copies=copies if copies is not None else self.copies)
             # host wall time of the (async) dispatch vs the fenced device span
             t1 = time.perf_counter()
             self._stats.dispatch_time_s += t1 - t0
             self._stats.hist.dispatch_s.record(t1 - t0)
-            if hasattr(out, "block_until_ready"):
-                out.block_until_ready()
+            with obs_trace.span("plan.fence", "plan", mode=mode):
+                if hasattr(out, "block_until_ready"):
+                    out.block_until_ready()
             t2 = time.perf_counter()
             self._stats.device_time_s += t2 - t0
             self._stats.total_time_s += t2 - t0
             self._stats.mttkrp_calls += 1
             self._stats.launches += 1        # one fused dispatch per call
-            if obs_trace.TRACING.enabled:
-                obs_trace.add_event("device.fence", "device", t0, t2,
-                                    backend=self.backend)
             if obs_ledger.LEDGER.enabled:
                 # fenced seconds (same t2 - t0 window as device_time_s);
                 # HBM bytes attributed from the per-kernel model
@@ -165,7 +165,7 @@ class StreamedPlan:
         if self._closed:
             raise RuntimeError("plan is closed")
         with obs_trace.span("plan.mttkrp", "plan", backend=self.backend,
-                            mode=mode):
+                            mode=mode), count_compiles(self._stats):
             return stream_mttkrp(
                 self._chunks, self.blco, factors, mode, queues=self.queues,
                 resolution=resolution if resolution is not None
@@ -241,14 +241,15 @@ class ShardedPlan:
             raise RuntimeError("plan is closed")
         self._stats.mttkrp_calls += 1
         self._stats.launches += 1
-        if self._run is None:
-            rank = factors[0].shape[1]
-            # empty-tensor case at the promoted precision, matching the
-            # sharded compute path (result_type of values vs factors)
-            out_dtype = jnp.result_type(
-                jnp.asarray(np.zeros(0, self._value_dtype)), factors[0])
-            return jnp.zeros((self.dims[mode], rank), out_dtype)
-        return self._run(factors, mode)
+        with count_compiles(self._stats):
+            if self._run is None:
+                rank = factors[0].shape[1]
+                # empty-tensor case at the promoted precision, matching the
+                # sharded compute path (result_type of values vs factors)
+                out_dtype = jnp.result_type(
+                    jnp.asarray(np.zeros(0, self._value_dtype)), factors[0])
+                return jnp.zeros((self.dims[mode], rank), out_dtype)
+            return self._run(factors, mode)
 
     def device_bytes(self) -> int:
         return 0 if self._closed else self._device_bytes
@@ -318,7 +319,8 @@ class BaselinePlan:
         if self._dev is None:
             raise RuntimeError("plan is closed")
         self._stats.mttkrp_calls += 1
-        return self._dev.mttkrp(factors, mode)
+        with count_compiles(self._stats):
+            return self._dev.mttkrp(factors, mode)
 
     def device_bytes(self) -> int:
         return self._dev.device_bytes() if self._dev is not None else 0

@@ -4,8 +4,10 @@ The cross-cutting layer every perf claim in this repo is measured
 through.  Six small modules, zero hard dependencies beyond the stdlib:
 
     trace    context-manager spans + already-measured events into a
-             thread-safe bounded ring buffer; zero-cost when disabled
-             (one module-level flag check, no allocation)
+             thread-safe bounded ring buffer (recording gated on one
+             module-level flag); every span is also a JAX profiler
+             annotation, on the device trace's clock; collector pauses
+             and backend compiles reported beside them
     hist     fixed log2-bucket histograms (dispatch latency, H2D chunk
              time, disk read time, queue wait, per-launch nnz) threaded
              through ``EngineStats`` / ``JobMetrics`` / ``ServiceMetrics``;
@@ -43,12 +45,12 @@ from .ledger import (DEVICE_HBM, DISK_HOST, EDGES, HOST_DEVICE, LEDGER,
                      verify_conservation)
 from .roofline import roofline_report
 from .slo import DEFAULT_SLOS, SLO, TelemetryExporter, slo_report
-from .trace import (TRACING, add_event, clear, disable, drain, enable,
-                    is_enabled, span, spans)
+from .trace import (TRACING, add_event, clear, compile_count, disable,
+                    drain, enable, is_enabled, span, spans, timed)
 
 __all__ = [
-    "trace", "TRACING", "span", "add_event", "enable", "disable",
-    "is_enabled", "clear", "spans", "drain",
+    "trace", "TRACING", "span", "timed", "add_event", "enable", "disable",
+    "is_enabled", "clear", "spans", "drain", "compile_count",
     "Hist", "EngineHists", "ServiceHists", "TenantHists",
     "chrome_trace", "write_chrome_trace", "track_totals",
     "render_prometheus",

@@ -71,6 +71,7 @@ class JobMetrics:
             "retries": self.stats.retries,
             "giveups": self.stats.giveups,
             "demotions": self.stats.demotions,
+            "compiles": self.stats.compiles,
             "hist": self.stats.hist.snapshot(),
         }
 

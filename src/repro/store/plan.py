@@ -17,7 +17,8 @@ from __future__ import annotations
 import os
 
 from repro.core.mttkrp import DEFAULT_COPIES, validate_kernel
-from repro.core.streaming import EngineStats, ReservationSpec, stream_mttkrp
+from repro.core.streaming import (EngineStats, ReservationSpec,
+                                  count_compiles, stream_mttkrp)
 from repro.obs import trace as obs_trace
 
 from .format import StoredBLCO, open_blco, save_blco
@@ -71,7 +72,7 @@ class DiskStreamedPlan:
         if self._closed:
             raise RuntimeError("plan is closed")
         with obs_trace.span("plan.mttkrp", "plan", backend=self.backend,
-                            mode=mode):
+                            mode=mode), count_compiles(self._stats):
             return stream_mttkrp(
                 self.stored.chunks(stats=self._stats), self.stored, factors,
                 mode, queues=self.queues,

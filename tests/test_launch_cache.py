@@ -303,3 +303,19 @@ def test_scan_steps_cut_launches_and_match_oracle(monkeypatch, backend):
         oracle = core.mttkrp_dense_oracle(t, factors, mode)
         assert _rel_err(plan.mttkrp(factors, mode), oracle) < 5e-4, mode
     plan.close()
+
+
+def test_scan_module_name_is_stable():
+    """The in-memory scan compiles to ``jit_stacked_mttkrp``: the trace
+    readers find the MTTKRP's device time under that name."""
+    import jax.numpy as jnp
+    from repro.core.launches import stacked_mttkrp
+    b = core.build_blco(_tensor(), max_nnz_per_block=256)
+    cache = LaunchCache.from_blco(b)
+    factors = tuple(jnp.asarray(f) for f in _factors(b.dims))
+    text = stacked_mttkrp.lower(
+        cache.hi, cache.lo, cache.vals, cache.bases, factors,
+        re_fields=cache.re_fields, re_shifts=cache.re_shifts, mode=0,
+        out_rows=b.dims[0], resolution="register", copies=1,
+        step=cache.reservation).as_text()
+    assert "module @jit_stacked_mttkrp" in text

@@ -23,6 +23,7 @@ JOB_KEYS = {
     "put_time_s", "disk_time_s", "dispatch_time_s", "device_time_s",
     "hist",
     "retries", "giveups", "demotions",
+    "compiles",
 }
 
 SERVICE_KEYS = {
@@ -48,6 +49,7 @@ ENGINE_STATS_KEYS = {
     "put_time_s", "disk_time_s", "dispatch_time_s", "device_time_s",
     "total_time_s", "hist",
     "retries", "giveups", "demotions",
+    "compiles",
 }
 
 HIST_KEYS = {"count", "sum", "min", "max", "buckets"}

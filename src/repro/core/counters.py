@@ -11,11 +11,16 @@ benchmarks can assert launch-count claims directly:
 
 The counter is monotonic; callers snapshot it before/after
 (``dispatch_count()``) rather than resetting, so concurrent readers never
-race each other's deltas.
+race each other's deltas.  Beside it, every MTTKRP call records the
+reduction it ran (``record_resolution``), so how often the MXU path engages
+is a count (``resolution_counts()``).
 """
 from __future__ import annotations
 
+import collections
+
 _dispatches = 0
+_resolutions: collections.Counter = collections.Counter()
 
 
 def record_dispatch(n: int = 1) -> None:
@@ -27,3 +32,13 @@ def record_dispatch(n: int = 1) -> None:
 def dispatch_count() -> int:
     """Monotonic count of compute dispatches recorded so far."""
     return _dispatches
+
+
+def record_resolution(resolution: str) -> None:
+    """Record one MTTKRP call whose reduction ran as ``resolution``."""
+    _resolutions[resolution] += 1
+
+
+def resolution_counts() -> dict:
+    """Monotonic MTTKRP calls so far, per resolution (a copy)."""
+    return dict(_resolutions)

@@ -23,12 +23,14 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .blco import BLCOTensor
+from .counters import record_resolution
 from .launches import scan_step_for, stacked_mttkrp
-from .mttkrp import DEFAULT_COPIES, choose_resolution
+from .mttkrp import DEFAULT_COPIES, auto_resolution
 from .padding import nnz_bytes, pad_bucket
 
 
@@ -79,15 +81,17 @@ def sharded_mttkrp_fn(mesh, *, re_fields: tuple, re_shifts: tuple,
     tuple of (I_n, R) factors, sharded over ``model_axis`` along R.  Each
     device scans its shard in steps of ``step`` slots (the single-device
     dataflow, ``stacked_mttkrp``) and the per-device outputs merge with one
-    ``psum`` over the data axis.
+    ``psum`` over the data axis.  The reduction is ``auto_resolution``'s,
+    from the output's dtype when the function is traced.
     """
     n_modes = len(re_fields)
     nnz_spec = P(data_axis)
     bases_spec = P(data_axis, None)
     factor_spec = P(None, model_axis)
-    resolution = choose_resolution(out_rows)
 
     def _shard_fn(hi, lo, vals, bases, factors):
+        resolution = auto_resolution(out_rows,
+                                     jnp.result_type(vals, factors[0]))
         out = stacked_mttkrp(hi, lo, vals, bases, factors,
                              re_fields=re_fields, re_shifts=re_shifts,
                              mode=mode, out_rows=out_rows,
@@ -133,9 +137,9 @@ def make_distributed_mttkrp(blco: BLCOTensor, mesh, *, data_axis="data",
 
     def run(factors, mode: int):
         factors = tuple(factors)
-        itemsize = np.result_type(blco.values.dtype,
-                                  factors[0].dtype).itemsize
-        return _build(mode, factors[0].shape[1], itemsize)(
+        out_dtype = np.result_type(blco.values.dtype, factors[0].dtype)
+        record_resolution(auto_resolution(blco.dims[mode], out_dtype))
+        return _build(mode, factors[0].shape[1], out_dtype.itemsize)(
             device_shards["idx_hi"], device_shards["idx_lo"],
             device_shards["vals"], device_shards["bases"], factors)
 

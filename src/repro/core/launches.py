@@ -31,8 +31,8 @@ import numpy as np
 from repro.obs import trace as obs_trace
 
 from .blco import BLCOTensor
-from .counters import record_dispatch
-from .mttkrp import (DEFAULT_COPIES, choose_resolution, launch_mttkrp_impl)
+from .counters import record_dispatch, record_resolution
+from .mttkrp import DEFAULT_COPIES, auto_resolution, launch_mttkrp_impl
 from . import padding
 from .padding import (device_memory_bytes, nnz_bytes, pad_bucket, pad_multiple,
                       scan_step)
@@ -227,21 +227,22 @@ class LaunchCache:
         if self.closed:
             raise RuntimeError("launch cache is closed")
         assert 0 <= mode < self.order
-        if resolution == "auto":
-            resolution = choose_resolution(self.dims[mode])
         factors = tuple(jnp.asarray(f) for f in factors)
+        out_dtype = jnp.result_type(self.vals, factors[0])
         if self.num_launches == 0:
             rank = factors[0].shape[1]
-            return jnp.zeros((self.dims[mode], rank),
-                             jnp.result_type(self.vals, factors[0]))
-        step = scan_step_for(
-            self.reservation, self.order, factors[0].shape[1],
-            jnp.result_type(self.vals, factors[0]).itemsize)
+            return jnp.zeros((self.dims[mode], rank), out_dtype)
+        if resolution == "auto":
+            resolution = auto_resolution(self.dims[mode], out_dtype)
+        step = scan_step_for(self.reservation, self.order,
+                             factors[0].shape[1], out_dtype.itemsize)
         record_dispatch()
+        record_resolution(resolution)
         # span covers the host-side issue of the one scan dispatch (async);
         # the wait for the device is the plan's plan.fence span
         with obs_trace.span("launch_cache.scan", "dispatch",
-                            launches=self.num_launches, mode=mode):
+                            launches=self.num_launches, mode=mode,
+                            resolution=resolution):
             return stacked_mttkrp(
                 self.hi, self.lo, self.vals, self.bases, factors,
                 re_fields=self.re_fields, re_shifts=self.re_shifts, mode=mode,

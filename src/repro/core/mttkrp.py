@@ -11,6 +11,10 @@ launch the dataflow is the paper's two phases:
       (§5.1.2), either directly ("register" resolution, §5.2) or via C partial
       copies merged at the end ("hierarchical" resolution, §5.1 steps 5-7).
 
+On a TPU the scatter of those updates runs one update row after another, so
+for modes up to ``MXU_MAX_ROWS`` rows the "mxu" resolution sums the rows
+into the output with a one-hot matmul instead (``auto_resolution``).
+
 The XLA path below is the faithful reference dataflow; `repro.kernels`
 provides the fused single-``pallas_call`` version of the whole pipeline.
 Both are validated against the dense matricization oracle in tests.
@@ -32,8 +36,8 @@ import numpy as np
 
 from . import u64
 from .blco import BLCOTensor
-from .counters import record_dispatch
-from .padding import pad_pow2
+from .counters import record_dispatch, record_resolution
+from .padding import pad_multiple, pad_pow2
 
 # TPU analogue of the paper's "#SMs" constant in the §5.3 heuristic: below this
 # target-mode length, update contention dominates and the hierarchical
@@ -42,6 +46,14 @@ CONTENTION_THRESHOLD = 128
 DEFAULT_COPIES = 8
 
 KERNELS = ("xla", "pallas")
+
+# Output rows up to which a TPU sums a float32 MTTKRP's rows with a one-hot
+# matmul on the MXU rather than a scatter-add.  The matmul costs O(rows) per
+# nnz slot, the scatter a constant.  On a TPU v5e the scan MTTKRP reads
+# 143-151 ns per slot with the scatter at every length, and with the matmul
+# 11.8 ns at 4,096 rows and 159 ns at 131,072: they cross near 124,700 rows
+# (scripts/mxu_crossover.py, PERF.md), here rounded down to 1024s.
+MXU_MAX_ROWS = 122_880
 
 
 def validate_kernel(kernel: str) -> str:
@@ -59,6 +71,20 @@ def validate_kernel(kernel: str) -> str:
 def choose_resolution(mode_len: int, threshold: int = CONTENTION_THRESHOLD) -> str:
     """Paper §5.3 adaptation heuristic, re-keyed for TPU (DESIGN.md §2)."""
     return "hierarchical" if mode_len < threshold else "register"
+
+
+def auto_resolution(mode_len: int, out_dtype) -> str:
+    """The reduction ``resolution="auto"`` runs on the XLA scan path.
+
+    "mxu" on a TPU for a float32 output of at most ``MXU_MAX_ROWS`` rows;
+    otherwise the paper's rule (``choose_resolution``).  float64 has no MXU
+    path, and XLA:CPU's serialized scatter needs no matmul.
+    """
+    if (jax.default_backend() == "tpu"
+            and jnp.dtype(out_dtype) == jnp.float32
+            and mode_len <= MXU_MAX_ROWS):
+        return "mxu"
+    return choose_resolution(mode_len)
 
 
 def delinearize(re_fields, re_shifts, idx_hi, idx_lo):
@@ -90,6 +116,49 @@ def _segment_compress(tgt, partial):
     return seg_tgt, seg_sums
 
 
+def _bf16_head(x):
+    """float32 ``x`` with all but its top 8 significand bits cleared: exact
+    in bfloat16.  Cut by a bit mask, not by a round trip through bfloat16,
+    which a TPU fusion may keep at float32 (XLA's excess precision)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
+
+
+def _split_bf16(p):
+    """float32 ``p`` (T, R) as three bfloat16 terms side by side, (T, 3R),
+    with ``p == t1 + t2 + t3``: each term holds the next 8 of the 24
+    significand bits, and each remainder is exact in float32.  Exact for
+    ``|p|`` above about 2^-100; below, a term may be subnormal and flush to
+    zero, an absolute error under 2^-126."""
+    t1 = _bf16_head(p)
+    r1 = p - t1
+    t2 = _bf16_head(r1)
+    return jnp.concatenate([t1, t2, r1 - t2], axis=1).astype(jnp.bfloat16)
+
+
+def _mxu_reduce(tgt, partial, out_rows: int):
+    """``out[i] = sum of partial[t] over tgt[t] == i`` as one matmul:
+    ``onehot(tgt)ᵀ @ terms`` in float32 on the MXU.
+
+    The one-hot is exact in bfloat16 and the three terms sum to the float32
+    partial exactly, so the result has float32 accuracy (the passes of a
+    ``HIGHEST`` dot, halved).  XLA fuses the iota compare into the matmul's
+    operand: no (T, rows) one-hot reaches memory.
+    """
+    rank = partial.shape[1]
+    rows = pad_multiple(out_rows, 128)          # the MXU's row tile
+    onehot = tgt[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+    # both operands are exact in bfloat16, so one MXU pass is exact whatever
+    # the process-wide matmul precision asks of float32 operands
+    acc = jax.lax.dot_general(
+        onehot.astype(jnp.bfloat16), _split_bf16(partial),
+        (((0,), (0,)), ((), ())), precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+    out = acc[:, :rank] + acc[:, rank:2 * rank] + acc[:, 2 * rank:]
+    return out[:out_rows]
+
+
 def launch_mttkrp_impl(idx_hi, idx_lo, vals, bases, factors, *,
                        re_fields: tuple, re_shifts: tuple, mode: int,
                        out_rows: int, resolution: str, copies: int):
@@ -111,6 +180,12 @@ def launch_mttkrp_impl(idx_hi, idx_lo, vals, bases, factors, *,
         partial = partial * jnp.take(f, coords[m], axis=0)
     tgt = coords[mode]
 
+    if resolution == "mxu":
+        # no segments and no copies: the matmul has no conflicts to resolve
+        if partial.dtype != jnp.float32:
+            raise ValueError(f"resolution 'mxu' sums float32 rows; the "
+                             f"output here is {partial.dtype}")
+        return _mxu_reduce(tgt, partial, out_rows)
     if resolution == "direct":
         # per-nnz scatter (no conflict resolution) — the COO dataflow on the
         # BLCO layout; cheapest on hardware with fast serialized scatter
@@ -208,6 +283,7 @@ def mttkrp_per_launch(blco: BLCOTensor, factors, mode: int, *,
     assert 0 <= mode < blco.order
     if resolution == "auto":
         resolution = choose_resolution(blco.dims[mode])
+    record_resolution(resolution)
     factors = tuple(jnp.asarray(f) for f in factors)
     rank = factors[0].shape[1]
     out = jnp.zeros((blco.dims[mode], rank),

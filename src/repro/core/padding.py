@@ -114,7 +114,8 @@ def scan_temp_bytes_per_nnz(order: int, rank: int, itemsize: int) -> int:
     tiling pads to 128 lanes, and the int32 index, coordinate and segment
     words.  ``compiled.memory_analysis()`` for v5e at NELL-2 widths (order
     3, f32) gives 801-805 B at rank 32 and 1059 B at rank 64; this model
-    gives 808 and 1064.
+    gives 808 and 1064.  The MXU reduction (the partial and its three
+    bfloat16 terms, no segment sums) gives 784-789 B at rank 32, under it.
     """
     return 2 * rank * itemsize + 4 * (128 + 2 * order + 4)
 

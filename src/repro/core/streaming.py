@@ -46,9 +46,9 @@ from repro.obs import trace as obs_trace
 from repro.obs.hist import EngineHists
 
 from .blco import BLCOTensor
-from .counters import record_dispatch
+from .counters import record_dispatch, record_resolution
 from .launches import scan_step_for, stacked_mttkrp
-from .mttkrp import choose_resolution, DEFAULT_COPIES
+from .mttkrp import DEFAULT_COPIES, auto_resolution, choose_resolution
 from .padding import next_pow2 as _next_pow2
 
 
@@ -244,8 +244,6 @@ def stream_mttkrp(chunks, blco: BLCOTensor, factors, mode: int, *,
     pipeline (``repro.kernels.fused``).
     """
     b = blco
-    if resolution == "auto":
-        resolution = choose_resolution(b.dims[mode])
     from .mttkrp import validate_kernel
     validate_kernel(kernel)
     if kernel == "pallas":
@@ -260,6 +258,12 @@ def stream_mttkrp(chunks, blco: BLCOTensor, factors, mode: int, *,
         val_dtype = b.values.dtype
     out_dtype = jnp.result_type(jnp.asarray(np.zeros(0, val_dtype)),
                                 factors[0])
+    if resolution == "auto":
+        # the fused kernel keeps the paper's rule; the scan may use the MXU
+        resolution = (choose_resolution(b.dims[mode]) if kernel == "pallas"
+                      else auto_resolution(b.dims[mode], out_dtype))
+    if kernel == "xla":
+        record_resolution(resolution)
     out = jnp.zeros((b.dims[mode], rank), out_dtype)
     stats = stats if stats is not None else StreamStats()
     step = None                       # the reservation's scan step, once known

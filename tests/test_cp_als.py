@@ -73,3 +73,35 @@ def test_reconstruction_shrinks_residual():
     resid = np.linalg.norm(dense - recon) / np.linalg.norm(dense)
     assert resid < 0.9
     assert abs((1 - resid) - res.fits[-1]) < 0.05   # fit formula consistency
+
+
+@pytest.mark.parametrize("cond", [1.0, 1e3, 1e6])
+def test_gram_solve_matches_float64_pseudo_inverse(cond):
+    """The mode update's solve against a positive definite Hadamard Gram
+    product agrees with a float64 ``M pinv(V)`` to float32 rounding times
+    the condition."""
+    from repro.core.cp_als import gram_solve
+    rng = np.random.default_rng(0)
+    q, _ = np.linalg.qr(rng.standard_normal((16, 16)))
+    v = (q * np.geomspace(1.0, 1.0 / cond, 16)) @ q.T
+    m = rng.standard_normal((50, 16))
+    want = m @ np.linalg.pinv(v)
+    got = np.asarray(gram_solve(jnp.asarray(m, jnp.float32),
+                                jnp.asarray(v, jnp.float32)), np.float64)
+    err = np.max(np.abs(got - want)) / np.max(np.abs(want))
+    assert err < 1e-5 * cond, err
+
+
+def test_gram_solve_singular_falls_back_to_pseudo_inverse():
+    """A singular ``V`` (two equal rank columns) has no Cholesky factor;
+    the update is then ``M pinv(V)``, finite."""
+    from repro.core.cp_als import gram_solve
+    rng = np.random.default_rng(1)
+    a = rng.standard_normal((6, 4)).astype(np.float32)
+    a[:, 3] = a[:, 2]
+    v = jnp.asarray(a.T @ a)
+    m = jnp.asarray(rng.standard_normal((9, 4)).astype(np.float32))
+    got = np.asarray(gram_solve(m, v))
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, np.asarray(m @ jnp.linalg.pinv(v)),
+                               rtol=1e-4, atol=1e-4)

@@ -97,6 +97,39 @@ def test_scan_mttkrp_fits_one_chip_at_nell2_widths(one_chip, stacked, rank):
         res, order=3, rank=rank, itemsize=4, device_bytes=V5E_BYTES_LIMIT)
 
 
+@pytest.mark.parametrize("mode", [0, 1, 2])
+def test_mxu_scan_fits_one_chip_at_nell2_widths(one_chip, mode):
+    """The in-memory scan with the MXU reduction (all launches stacked,
+    rank 32) compiles for one v5e at NELL-2 widths on every mode and fits
+    the chip.  XLA fuses the one-hot's iota compare into the matmul: the
+    temporaries hold no (step, rows) one-hot, staying within the byte
+    predictor's model and a few times the step's (step, 3R) bfloat16
+    terms."""
+    launch = default_launch_nnz(3, 4, V5E_BYTES_LIMIT)
+    res = default_reservation(launch)
+    lead = (-(-NELL2_NNZ // launch),)
+    step = scan_step(res, order=3, rank=RANK, itemsize=4,
+                     device_bytes=V5E_BYTES_LIMIT)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(lead + shape, dtype, sharding=one_chip)
+
+    fields, shifts = _fields()
+    compiled = stacked_mttkrp.lower(
+        s((res,), jnp.uint32), s((res,), jnp.uint32), s((res,), jnp.float32),
+        s((res, 3), jnp.int32), _factors(one_chip),
+        re_fields=fields, re_shifts=shifts, mode=mode,
+        out_rows=NELL2_DIMS[mode], resolution="mxu", copies=8,
+        step=step).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes)
+    assert total < V5E_BYTES_LIMIT, total
+    assert m.temp_size_in_bytes <= scan_temp_bytes(
+        res, order=3, rank=RANK, itemsize=4, device_bytes=V5E_BYTES_LIMIT)
+    assert m.temp_size_in_bytes <= 5 * step * 3 * RANK * 2
+
+
 @pytest.mark.parametrize("variant,out_rows", [("segment", NELL2_DIMS[0]),
                                               ("stash", 512)])
 def test_fused_kernel_refused_by_mosaic(one_chip, variant, out_rows):

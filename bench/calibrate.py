@@ -3,14 +3,15 @@
 
     python bench/calibrate.py --workload <cell> --seeds 11 --seconds 12
 
-One process, one TPU: for each seed a whole run of the cell (tensor, BLCO,
-plan, warm-up, window, comparison), printing as one JSON line per seed the
-check's three numbers for the program and for the control, the reference
-computed in bfloat16 and put in the program's place.  The lower reading of
-a number is the largest the program gives over the seeds; the upper, the
-smallest the control gives.  The benchmark's own runs never run the
-control.  Give each process one seed, as a benchmark run has: a process's
-host memory only grows from seed to seed.
+One process on the cell's chips: for each seed a whole run of the cell
+(tensor, BLCO, plan, warm-up, window, comparison), printing as one JSON
+line per seed the check's three numbers for the program and for the
+control, the reference computed in bfloat16 and put in the program's
+place.  The lower reading of a number is the largest the program gives
+over the seeds; the upper, the smallest the control gives.  The
+benchmark's own runs never run the control.  Give each process one seed,
+as a benchmark run has: a process's host memory only grows from seed to
+seed.
 """
 from __future__ import annotations
 
@@ -34,20 +35,21 @@ def main(argv=None) -> int:
     cell = run.load_cell(args.workload)
 
     import jax
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print(f"calibrate: needs a TPU; JAX found {dev.platform}",
-              file=sys.stderr)
+    devs = jax.devices()
+    if devs[0].platform != "tpu" or len(devs) < cell["chips"]:
+        print(f"calibrate: needs {cell['chips']} TPU chip(s); JAX found "
+              f"{len(devs)} {devs[0].platform} device(s)", file=sys.stderr)
         return run.EXIT_NO_CHIP
-    peaks = run.work.peaks_for(dev.device_kind)
+    devs = devs[:cell["chips"]]
+    peaks = run.work.peaks_for(devs[0].device_kind)
     run.enable_compile_cache(jax)
     clock = run.CompileClock(jax)
     program, control = {}, {}
     for seed in args.seeds:
-        line = run.run_cell(cell, seed, args.seconds, False, device=dev,
-                            bytes_limit=int(dev.memory_stats()["bytes_limit"]),
-                            peaks=peaks, t_start=time.perf_counter(),
-                            clock=clock, controls=(CONTROL,))
+        line = run.run_cell(cell, seed, args.seconds, False, devices=devs,
+                            bytes_limits=run.chip_limits(devs), peaks=peaks,
+                            t_start=time.perf_counter(), clock=clock,
+                            controls=(CONTROL,))
         got = {k: v["value"] for k, v in line["checks"].items()}
         print(json.dumps({"seed": seed, "correct": line["correct"],
                           "program": got, "control": line["control"],

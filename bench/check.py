@@ -19,7 +19,10 @@ over the sample:
   sizes).
 
 A sweep that made other than one MTTKRP per mode, or a non-finite fit, is
-a fault and makes the run incorrect whatever the numbers say.
+a fault and makes the run incorrect whatever the numbers say.  Beside the
+numbers, and compared with nothing, ``compare`` reports the largest
+condition number of the sampled updates' ``V``: ``update_err`` grows with
+it.
 """
 from __future__ import annotations
 
@@ -138,7 +141,7 @@ def compare(sweeps: list[Sweep], coo: reference.COO, rank: int,
     nums = dict.fromkeys(NUMBERS, 0.0)
     ctrl = {c: dict.fromkeys(NUMBERS, 0.0) for c in controls}
     out = {"numbers": nums, "control": ctrl, "faults": faults,
-           "sweeps": picks, "cols": cols.tolist()}
+           "sweeps": picks, "cols": cols.tolist(), "condition": 0.0}
     if faults:
         return out
 
@@ -159,6 +162,7 @@ def compare(sweeps: list[Sweep], coo: reference.COO, rank: int,
                 scale_q = np.max(np.abs(ref_q))
                 worse(nums, "mttkrp_err",
                       np.max(np.abs(m_out @ dirs - ref_q)) / scale_q)
+            worse(out, "condition", reference.condition(fin, mode))
             want = reference.mode_update(m_out, fin, mode)
             new = _to_host(s.calls[k + 1][0][mode] if k + 1 < order
                            else s.factors[mode])

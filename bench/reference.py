@@ -141,6 +141,13 @@ def mode_update(m_mat, factors, mode: int,
     return p.round(a / lam)
 
 
+def condition(factors, mode: int) -> float:
+    """Condition number of the mode update's ``V``, the Hadamard product
+    of the other factors' Grams, in float64."""
+    return float(np.linalg.cond(
+        _gram_product(factors, mode, Precision("float64"))))
+
+
 def fit(norm_x: float, factors, lam, m_last,
         precision: str = "float64") -> tuple[float, float]:
     """CP fit of ``(lam, factors)``, and the scale of its rounding.

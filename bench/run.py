@@ -6,14 +6,16 @@
 A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a tensor
 configuration (``bench/configs/<config>.json``) and a regime
 (``bench/traffic/<traffic>.json``: the device budget rule and the regime
-``plan_for`` must pick under it).  The run:
+``plan_for`` must pick under it; the ``sharded`` regime runs over a mesh
+of the cell's chips).  The run:
 
 1. fails without a TPU, with fewer chips than the cell asks for, or on a
    device kind missing from ``bench/peaks.json``;
 2. set-up: makes the tensor from ``--seed`` (``bench/gen.py``), builds
    BLCO with the program's ``build_blco``, plans it with ``plan_for`` under
-   the cell's budget (asserting the cell's regime and no demotion), and
-   warms up with one CP-ALS sweep of the cell's own shapes;
+   the cell's budget, over its mesh where the regime is ``sharded``
+   (asserting the cell's regime and no demotion), and warms up with CP-ALS
+   sweeps of the cell's own shapes until one compiles nothing;
 3. window: ``cp_als_step`` sweeps back to back until ``--seconds`` have
    passed; the sweep in progress then ends the window.  With ``--trace 1``
    the profiler records the window;
@@ -55,6 +57,7 @@ from bench import check, gen, reference, work                # noqa: E402
 EXIT_NO_CHIP = 3
 EXIT_UNKNOWN_DEVICE = 4
 EXIT_WRONG_REGIME = 5
+WARMUP_MAX_SWEEPS = 4
 # The float32 matmuls of the mode update and the fit.  At JAX's default a
 # TPU makes them in one bfloat16 pass, which no check can tell from the
 # bfloat16 control; the program sets no precision of its own yet (PERF.md,
@@ -145,11 +148,29 @@ def profiler_options():
     return opts
 
 
-def device_budget(rule: dict, bytes_limit: int) -> int:
-    """The device budget a traffic file's rule gives."""
+def device_budget(rule: dict, bytes_limits: list) -> int:
+    """The device budget a traffic file's rule gives, from the
+    ``bytes_limit`` of each of the cell's chips: each chip's less
+    ``bytes``, summed over the chips."""
     if rule["rule"] == "device_limit_less":
-        return bytes_limit - rule["bytes"]
+        return sum(b - rule["bytes"] for b in bytes_limits)
     raise ValueError(f"unknown budget rule {rule['rule']!r}")
+
+
+def cell_mesh(traffic: dict, devices: list):
+    """The mesh of a ``sharded`` cell: its chips on ``data``, 1 on
+    ``model`` (nnz split over the chips, factors replicated); None for
+    any other regime."""
+    if traffic["regime"] != "sharded":
+        return None
+    from jax.sharding import Mesh
+    return Mesh(np.array(devices).reshape(len(devices), 1),
+                ("data", "model"))
+
+
+def chip_limits(devices: list) -> list:
+    """The ``bytes_limit`` of each chip."""
+    return [int(d.memory_stats()["bytes_limit"]) for d in devices]
 
 
 def host_peak_rss() -> int:
@@ -171,10 +192,11 @@ def stats_delta(after: dict, before: dict) -> dict:
 
 # --------------------------------------------------------------------- run
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
-             device, bytes_limit: int, peaks: dict, t_start: float,
+             devices: list, bytes_limits: list, peaks: dict, t_start: float,
              clock: CompileClock, trace_dir: Path | None = None,
              controls: tuple = ()) -> dict:
-    """Set up, measure and check one run of ``cell``; the result line.
+    """Set up, measure and check one run of ``cell`` on ``devices`` (the
+    cell's chips, with the ``bytes_limit`` of each); the result line.
 
     ``controls`` (precisions) also reads the check's numbers for the
     reference at each precision in the program's place, under
@@ -203,10 +225,13 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     setup["blco_build_s"] = time.perf_counter() - t0
 
     t0 = time.perf_counter()
-    budget = device_budget(traffic["budget"], bytes_limit)
-    plan = plan_for(blco, budget, rank=rank)
+    budget = device_budget(traffic["budget"], bytes_limits)
+    mesh = cell_mesh(traffic, devices)
+    plan = plan_for(blco, budget, rank=rank, mesh=mesh)
     demotions = plan.stats().demotions
-    say(f"plan: {plan.backend} under a budget of {budget:,} B, "
+    say(f"plan: {plan.backend} under a budget of {budget:,} B"
+        + (f" over a mesh {dict(mesh.shape)}" if mesh is not None else "")
+        + ", "
         f"{len(blco.launches)} launches, {plan.device_bytes():,} B on the "
         f"device, {demotions} demotions")
     if plan.backend != traffic["regime"] or demotions:
@@ -222,10 +247,18 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
 
     rec = check.Recorder(plan, TraceAnnotation)
     t0 = time.perf_counter()
-    rec.begin_sweep()
-    core.cp_als_step(rec, state)
-    rec.end_sweep(state)
+    # until a sweep compiles nothing: the sharded plan compiles each mode
+    # again in its second sweep, which meets the factors on the mesh where
+    # the first met them on one chip
+    for sweeps in range(1, WARMUP_MAX_SWEEPS + 1):
+        programs = clock.programs
+        rec.begin_sweep()
+        core.cp_als_step(rec, state)
+        rec.end_sweep(state)
+        if clock.programs == programs:
+            break
     setup["warmup_s"] = time.perf_counter() - t0
+    setup["warmup_sweeps"] = sweeps
     setup["compile_s"] = clock.seconds - compiled_at_start[0]
     setup["programs"] = clock.programs - compiled_at_start[1]
     setup["cache_hits"] = clock.cache_hits - compiled_at_start[2]
@@ -260,8 +293,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
         jax.profiler.stop_trace()
     compiled_in_window = clock.programs - compiled_before
     stats = stats_delta(plan.stats().snapshot(), stats_before)
-    mem = device.memory_stats() or {}
-    peak_bytes = int(mem.get("peak_bytes_in_use", 0))
+    # the fullest of the cell's chips
+    peak_bytes = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                     for d in devices)
     plan.close()
     del blco
 
@@ -285,18 +319,21 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
                            controls=controls)
     correct = check.verdict(result, cell["limits"])
     say(f"check: sweeps {result['sweeps']}, rank columns {result['cols']}, "
+        f"condition of V up to {result['condition']:.4g}, "
         f"{time.perf_counter() - t0:.2f} s, host peak RSS {host_peak_rss():,} B"
         + "".join(
             f"; fault: {f}" for f in result["faults"]))
 
     record = {
         "dims": dims, "nnz": conf["nnz"], "rank": rank,
-        "value_dtype": conf["dtype"], "peaks": peaks, "sweeps": len(walls),
+        "value_dtype": conf["dtype"], "peaks": peaks, "chips": len(devices),
+        "sweeps": len(walls),
         "call_modes": [m for s in rec.sweeps for _, m, _ in s.calls],
         "stats": stats, "memory_peak_bytes": peak_bytes, "setup": setup,
         "trace": None,
     }
-    out_device = {"platform": device.platform, "kind": device.device_kind,
+    out_device = {"platform": devices[0].platform,
+                  "kind": devices[0].device_kind,
                   "count": len(jax.devices()),
                   "memory_peak_bytes": peak_bytes}
     line = {"correct": correct, "attempted": len(walls),
@@ -361,8 +398,8 @@ def main(argv=None) -> int:
     cache = enable_compile_cache(jax)
     say(f"device: {devs[0].device_kind} x{len(devs)}, compile cache {cache}")
     line = run_cell(cell, args.seed, args.seconds, bool(args.trace),
-                    device=devs[0],
-                    bytes_limit=int(devs[0].memory_stats()["bytes_limit"]),
+                    devices=devs[:cell["chips"]],
+                    bytes_limits=chip_limits(devs[:cell["chips"]]),
                     peaks=peaks, t_start=T_START, clock=CompileClock(jax),
                     trace_dir=TRACE_ROOT / args.workload)
     for k, v in line["checks"].items():

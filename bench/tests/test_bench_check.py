@@ -43,8 +43,8 @@ def small_launches(monkeypatch):
 def small_run(name, clock, seed=2 ** 33 + 7, controls=()):
     cell = run.load_cell(name)
     cell["config"] = dict(cell["config"], nnz=NNZ)
-    return run.run_cell(cell, seed, 0.2, False, device=jax.devices()[0],
-                        bytes_limit=8 << 30, peaks=PEAKS, t_start=0.0,
+    return run.run_cell(cell, seed, 0.2, False, devices=jax.devices()[:1],
+                        bytes_limits=[8 << 30], peaks=PEAKS, t_start=0.0,
                         clock=clock, controls=controls)
 
 

@@ -55,11 +55,12 @@ def test_roofline_reader():
             return (0, 10 ** 9)
 
         def module_ns(self, prefix, intervals=None):
-            return 5 * 10 ** 8 if prefix == "jit_stacked_mttkrp" else 0
+            return 5 * 10 ** 8 if "jit_stacked_mttkrp(1)".startswith(
+                prefix) else 0
 
     rec = {"trace": Trace(), "call_modes": [0, 1, 2], "dims": NELL2,
            "nnz": 25_000_000, "rank": 32, "value_dtype": "float32",
-           "peaks": PEAKS}
+           "peaks": PEAKS, "chips": 1}
     least = sum(work.least_seconds(NELL2, 25_000_000, 32, "float32", m, PEAKS)
                 for m in range(3))
     assert run.metric_reader("mttkrp_roofline")(rec) == \

@@ -94,7 +94,8 @@ def test_recorded_trace():
     assert gaps and all(n.startswith("bench.") and t > 0 for n, t in gaps)
     rec = {"trace": tr, "call_modes": [0, 1, 2, 3], "dims": (6186, 24, 77, 32),
            "nnz": 20_000, "rank": 32, "value_dtype": "float32",
-           "peaks": {"hbm_bytes_per_s": 819e9, "flops_per_s": 197e12}}
+           "peaks": {"hbm_bytes_per_s": 819e9, "flops_per_s": 197e12},
+           "chips": 1}
     share = run.metric_reader("mttkrp_roofline")(rec)
     assert 0 < share < 100
     assert 0 < run.metric_reader("device.idle_share")(rec) < 100

@@ -9,7 +9,9 @@ plane, where the benchmark's ``jax.profiler.TraceAnnotation`` spans
 * busy time: the union of the op intervals of each device inside the
   window, averaged over the devices; idle share is 1 - busy / window;
 * module time: summed durations of the executions of one jitted module
-  (``jit_stacked_mttkrp``), optionally inside given intervals;
+  (``jit_stacked_mttkrp``), optionally inside given intervals, averaged
+  over the devices; likewise the time of one kind of op (an HLO opcode,
+  such as ``all-reduce``) inside a module;
 * breakdown: the device ops that took most time, and the longest idle
   gaps, each named by the innermost benchmark span around its middle.
 """
@@ -18,11 +20,15 @@ from __future__ import annotations
 import dataclasses
 import glob
 import os
+import re
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench."
 TOP = 10
+# the opcode of an HLO instruction's text: the first lower-case word before
+# a parenthesis that follows a space (layouts such as ``T(8,128)`` do not)
+_OPCODE = re.compile(r"\s([a-z][a-z0-9_-]*)\(")
 
 
 @dataclasses.dataclass
@@ -30,6 +36,7 @@ class DeviceTrace:
     ops: list          # per device: sorted [(start_ns, end_ns, name)]
     modules: list      # per device: sorted [(start_ns, end_ns, name)]
     spans: list        # host benchmark spans: [(start_ns, end_ns, name)]
+    opcodes: dict = dataclasses.field(default_factory=dict)  # op name: opcode
 
     @property
     def devices(self) -> int:
@@ -59,22 +66,19 @@ class DeviceTrace:
                                     for s, e, _ in ops if e > w0 and s < w1])
         return total / len(self.ops)
 
-    def module_ns(self, prefix: str, intervals=None) -> float:
-        """Summed durations of ``prefix*`` module executions (inside any of
-        ``intervals`` when given), averaged over devices."""
-        if not self.modules:
-            return 0.0
-        total = 0
-        for mods in self.modules:
-            for s, e, n in mods:
-                if not n.startswith(prefix):
-                    continue
-                if intervals is None:
-                    total += e - s
-                else:
-                    total += sum(max(0, min(e, b) - max(s, a))
-                                 for a, b in intervals)
-        return total / len(self.modules)
+    def module_ns(self, prefix, intervals=None) -> float:
+        """Summed durations of ``prefix*`` module executions (``prefix`` a
+        string or a tuple of them; inside any of ``intervals`` when given),
+        averaged over devices."""
+        return _summed(self.modules, lambda n: n.startswith(prefix),
+                       intervals)
+
+    def op_ns(self, prefix: str, opcode: str, intervals=None) -> float:
+        """Summed durations of the ops of HLO opcode ``opcode*`` inside
+        ``prefix*`` modules (inside any of ``intervals`` when given),
+        averaged over devices."""
+        return _summed(self.ops, lambda n: n.startswith(prefix + "/") and
+                       self.opcodes.get(n, "").startswith(opcode), intervals)
 
     def top_ops(self, window=None, top: int = TOP) -> list:
         """``[[op name, seconds]]`` of the ops that took most device time."""
@@ -140,7 +144,7 @@ def load(path: str) -> DeviceTrace:
     from jax.profiler import ProfileData
     with open(path, "rb") as f:
         data = ProfileData.from_serialized_xspace(f.read())
-    ops, modules, spans = [], [], []
+    ops, modules, spans, opcodes = [], [], [], {}
     for plane in data.planes:
         if plane.name.startswith("/device:") and "TPU" in plane.name \
                 and "SparseCore" not in plane.name:
@@ -148,13 +152,35 @@ def load(path: str) -> DeviceTrace:
             if OPS_LINE not in lines and MODULES_LINE not in lines:
                 continue
             mods = _events(lines.get(MODULES_LINE))
-            ops.append(_name_ops(_events(lines.get(OPS_LINE)), mods))
+            raw = _events(lines.get(OPS_LINE))
+            ops.append(_name_ops(raw, mods))
             modules.append(mods)
+            for (_, _, hlo), (_, _, name) in zip(raw, ops[-1]):
+                opcodes.setdefault(name, opcode(hlo))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 spans.extend(e for e in _events(line)
                              if e[2].startswith(SPAN_PREFIX))
-    return DeviceTrace(ops=ops, modules=modules, spans=sorted(spans))
+    return DeviceTrace(ops=ops, modules=modules, spans=sorted(spans),
+                       opcodes=opcodes)
+
+
+def _summed(per_device: list, keep, intervals) -> float:
+    """Summed durations of the events whose name ``keep`` accepts (inside
+    any of ``intervals`` when given), averaged over devices."""
+    if not per_device:
+        return 0.0
+    total = 0
+    for events in per_device:
+        for s, e, n in events:
+            if not keep(n):
+                continue
+            if intervals is None:
+                total += e - s
+            else:
+                total += sum(max(0, min(e, b) - max(s, a))
+                             for a, b in intervals)
+    return total / len(per_device)
 
 
 def short_name(hlo: str) -> str:
@@ -162,6 +188,14 @@ def short_name(hlo: str) -> str:
     ``jit_stacked_mttkrp(7182)`` -> ``jit_stacked_mttkrp``."""
     name = hlo.split(" = ", 1)[0].lstrip("%")
     return name.split("(", 1)[0] if name.endswith(")") else name
+
+
+def opcode(hlo: str) -> str:
+    """``%psum.7 = f32[..]{1,0:T(8,128)} all-reduce(%fusion.4), ..`` ->
+    ``all-reduce``; "" where the text is no HLO instruction."""
+    _, sep, rest = hlo.partition(" = ")
+    m = _OPCODE.search(" " + rest) if sep else None
+    return m.group(1) if m else ""
 
 
 def _name_ops(ops: list, modules: list) -> list:
